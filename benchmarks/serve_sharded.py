@@ -80,6 +80,8 @@ def _src_path() -> str:
 
 def _width_sweep(widths: tuple[int, ...]) -> dict:
     env = dict(os.environ)
+    # a host-device mesh on the CPU: the parent may hold the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _src_path()
     code = f"WIDTHS = {widths!r}\n" + textwrap.dedent(_WIDTH_CODE)

@@ -99,6 +99,9 @@ def run_one(exp: Experiment, device: str, quick: bool = False,
 
 
 def _worker_init(trace_cache_root: str | None) -> None:
+    # workers run simulator records only; pinned to the CPU, none of them
+    # can open the accelerator that the parent may hold
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from repro import jaxcache
     jaxcache.enable_env()        # env-only: jax stays lazy until needed
     registry.discover()
@@ -138,9 +141,9 @@ def _run_pooled(tasks: list[tuple[Experiment, str]], opts: RunOptions,
     def cost(i: int) -> float:
         return costs.get((tasks[i][0].name, tasks[i][1]), float("inf"))
 
-    # TPU records run as ONE sequential batch on one worker: they share a
-    # single jax import + XLA warmup instead of paying it per worker, and
-    # they overlap the simulator records on the other workers.
+    # TPU records run in this process, one after another, while the
+    # simulator records run on the CPU-pinned workers: one process per
+    # chip, and the TPU records share one jax import and warmup
     tpu_idx = [i for i, (_, dev) in enumerate(tasks)
                if device_registry.get_device(dev).kind == "tpu"]
     solo_idx = [i for i in range(len(tasks)) if i not in set(tpu_idx)]
@@ -151,28 +154,22 @@ def _run_pooled(tasks: list[tuple[Experiment, str]], opts: RunOptions,
             max_workers=jobs, initializer=_worker_init,
             initargs=(opts.trace_cache_root,)) as pool:
         futures = []
-        if len(tpu_idx) > 1:
-            for i in tpu_idx:
-                if progress:
-                    progress(f"{tasks[i][0].name} × {tasks[i][1]}")
-            batch = [(tasks[i][0].name, tasks[i][1],
-                      record_seed(opts.seed, tasks[i][0].name, tasks[i][1]))
-                     for i in tpu_idx]
-            futures.append((tpu_idx, pool.submit(
-                _worker_run_batch, batch, opts.quick)))
-        else:
-            solo_idx = sorted(solo_idx + tpu_idx, key=lambda i: -cost(i))
         for i in solo_idx:
             exp, dev = tasks[i]
             if progress:
                 progress(f"{exp.name} × {dev}")
-            futures.append(([i], pool.submit(
+            futures.append((i, pool.submit(
                 _worker_run_batch,
                 [(exp.name, dev, record_seed(opts.seed, exp.name, dev))],
                 opts.quick)))
-        for idxs, fut in futures:
-            for i, rec in zip(idxs, fut.result()):
-                results[i] = rec
+        for i in tpu_idx:
+            exp, dev = tasks[i]
+            if progress:
+                progress(f"{exp.name} × {dev}")
+            results[i] = run_one(exp, dev, quick=opts.quick,
+                                 seed=record_seed(opts.seed, exp.name, dev))
+        for i, fut in futures:
+            results[i] = fut.result()[0]
     # original task order, not completion or submission order
     return results
 

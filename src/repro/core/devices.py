@@ -332,6 +332,20 @@ class TpuSpec:
 
 TPU_V5E = TpuSpec()
 
+#: published spec of each chip, keyed by the ``device_kind`` JAX reports
+#: for it (peaks: Google Cloud documentation, "TPU v5e").  A kind that is
+#: missing has no spec: a chip is never priced with another chip's peaks.
+TPU_SPECS_BY_KIND: dict[str, TpuSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def tpu_spec_for_kind(device_kind: str) -> TpuSpec:
+    """The published spec of an attached chip; raises for unknown kinds."""
+    if device_kind not in TPU_SPECS_BY_KIND:
+        raise ValueError(
+            f"no published spec for device kind {device_kind!r}; known: "
+            f"{sorted(TPU_SPECS_BY_KIND)}")
+    return TPU_SPECS_BY_KIND[device_kind]
+
 # ---------------------------------------------------------------------------
 # Device registry (the hook `repro.bench` parameterizes experiments over)
 # ---------------------------------------------------------------------------

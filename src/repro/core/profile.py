@@ -380,6 +380,11 @@ def resolve_spec(spec: "DeviceProfile | TpuSpec | None" = None) -> TpuSpec:
     a :class:`TpuSpec` passes through.  All former ``spec=TPU_V5E``
     defaults route here, so a launcher-installed profile reaches every
     downstream decision without threading a parameter through each call.
+
+    The fallback is a modelled device, not the attached one: code that
+    prices the chip it runs on passes
+    ``devices.tpu_spec_for_kind(device.device_kind)``, which refuses a
+    kind it has no spec for.
     """
     if spec is None:
         spec = _ACTIVE if _ACTIVE is not None else TPU_V5E

@@ -49,19 +49,16 @@ def enable_env(cache_dir: str | None = None) -> str | None:
 def enable(cache_dir: str | None = None) -> str | None:
     """Point JAX's persistent compilation cache at ``cache_dir``.
 
-    Returns the directory in use, or None when disabled/unavailable.
+    Returns the directory in use, or None when disabled.
     """
     if os.environ.get("REPRO_NO_JAX_CACHE"):
         return None
     import jax
     cache_dir = cache_dir or default_dir()
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every computation: on CPU even small compiles add up across
-        # a 140-test suite, and the cache is size-bounded by the workspace
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:
-        return None                      # older jax: silently run uncached
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # cache every computation: on CPU even small compiles add up across
+    # a 140-test suite, and the cache is size-bounded by the workspace
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     return cache_dir

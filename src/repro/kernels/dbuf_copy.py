@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _dbuf_kernel(x_hbm, o_hbm, bufs, in_sems, out_sems, *,
                  block_rows: int, nblocks: int, num_buffers: int):
@@ -64,7 +66,7 @@ def _dbuf_kernel(x_hbm, o_hbm, bufs, in_sems, out_sems, *,
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "num_buffers", "interpret"))
 def dbuf_copy(x: jax.Array, *, block_rows: int = 256, num_buffers: int = 2,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """Copy (rows, cols) through `num_buffers` VMEM slots of block_rows."""
     rows, cols = x.shape
     if rows % block_rows:
@@ -82,5 +84,5 @@ def dbuf_copy(x: jax.Array, *, block_rows: int = 256, num_buffers: int = 2,
             pltpu.SemaphoreType.DMA((num_buffers,)),
             pltpu.SemaphoreType.DMA((num_buffers,)),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

@@ -23,10 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-# either so the kernel (and the examples driving it) survive the pin
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from repro.kernels import resolve_interpret
 
 _NEG_BIG = -1e30
 
@@ -86,7 +83,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     num_q_heads: int, num_kv_heads: int,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B·H, S, D); k/v: (B·Hkv, S, D) — GQA folded into the lead axis."""
     bh, sq, d = q.shape
     bhkv, sk, _ = k.shape
@@ -121,7 +118,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _memcpy_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...]
@@ -29,7 +31,7 @@ def _memcpy_kernel(x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def memcpy(x: jax.Array, *, block_rows: int = 256,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool | None = None) -> jax.Array:
     """Copy a (rows, cols) array through VMEM in (block_rows, cols) tiles."""
     rows, cols = x.shape
     if rows % block_rows:
@@ -40,5 +42,5 @@ def memcpy(x: jax.Array, *, block_rows: int = 256,
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

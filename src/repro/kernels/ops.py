@@ -1,7 +1,11 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies execute in Python exactly as written) and False on real TPU.
+``interpret=None`` (the default) follows the backend through
+:func:`repro.kernels.resolve_interpret`: on a TPU every kernel compiles
+with Mosaic; on the CPU, where the tests run (``JAX_PLATFORMS=cpu``), the
+kernel bodies execute in the Pallas interpreter exactly as written.
+``tests/test_tpu_compile.py`` compiles the kernels for a described v5e
+chip, so a kernel Mosaic refuses fails there and not only on the chip.
 Model code calls these through ``attention()`` which picks the flash kernel
 or the jnp reference per config (`attention_impl`), so the dry-run can
 lower pure-XLA attention while kernel correctness is pinned by tests.
@@ -11,9 +15,7 @@ from __future__ import annotations
 
 import time
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import memcpy as _mc
@@ -22,19 +24,13 @@ from repro.kernels import ref
 from repro.kernels import strided as _st
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # -- pointer chase -----------------------------------------------------------
 
 
 def pchase_trace(array, iterations: int, start: int = 0, *,
-                 line_elems: int = 8, interpret: bool | None = None):
+                 interpret: bool | None = None):
     return _pc.pchase_trace(jnp.asarray(array, jnp.int32), start,
-                            iterations=iterations, line_elems=line_elems,
-                            interpret=_default_interpret()
-                            if interpret is None else interpret)
+                            iterations=iterations, interpret=interpret)
 
 
 def pchase_latency_slope(array, k_small: int, k_large: int, *,
@@ -57,9 +53,7 @@ def pchase_latency_slope(array, k_small: int, k_large: int, *,
 
 
 def memcpy(x, *, block_rows: int = 256, interpret: bool | None = None):
-    return _mc.memcpy(x, block_rows=block_rows,
-                      interpret=_default_interpret()
-                      if interpret is None else interpret)
+    return _mc.memcpy(x, block_rows=block_rows, interpret=interpret)
 
 
 def memcpy_throughput_gbps(shape=(4096, 512), *, block_rows: int = 256,
@@ -80,9 +74,7 @@ def memcpy_throughput_gbps(shape=(4096, 512), *, block_rows: int = 256,
 
 
 def strided_gather(x, stride: int, *, interpret: bool | None = None):
-    return _st.strided_gather(x, stride=stride,
-                              interpret=_default_interpret()
-                              if interpret is None else interpret)
+    return _st.strided_gather(x, stride=stride, interpret=interpret)
 
 
 # -- attention ---------------------------------------------------------------
@@ -95,7 +87,7 @@ def flash_attention(q, k, v, *, num_q_heads: int, num_kv_heads: int,
     return _fa.flash_attention(
         q, k, v, num_q_heads=num_q_heads, num_kv_heads=num_kv_heads,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=_default_interpret() if interpret is None else interpret)
+        interpret=interpret)
 
 
 def attention(q, k, v, *, num_q_heads: int, num_kv_heads: int,

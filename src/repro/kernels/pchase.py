@@ -2,9 +2,12 @@
 
 Faithful structure: ``j = A[j]`` in a serial loop, with the visited index
 recorded per iteration (the paper's ``s_index[]`` in shared memory → our
-VMEM trace buffer).  The chase array lives in HBM (``memory_space=ANY``);
-every dereference issues one line-sized DMA into a VMEM scratch line —
-deliberately uncached, exactly the transaction the paper measures.
+SMEM trace buffer).  The chase array lives in HBM (``memory_space=ANY``)
+as rows of ``LANES`` int32; every dereference issues one DMA of the
+aligned row holding its target into an SMEM scratch line — deliberately
+uncached, exactly the transaction the paper measures.  A row is the
+smallest HBM→SMEM transfer Mosaic lowers, and SMEM is where a scalar
+index has to land for the next address computation.
 
 TPU adaptation (DESIGN.md §2/§4): Pallas-TPU exposes no in-kernel cycle
 counter, so per-access *latency* comes from host-side differential timing
@@ -23,38 +26,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
+#: int32 lanes in one HBM row of the chase array: a dereference moves one
+#: such 512-byte row
+LANES = 128
+
 
 def _pchase_kernel(start_ref, a_ref, o_ref, line_ref, sem):
     """One serial chase; o_ref[t] = the t-th visited index."""
 
     def body(t, j):
-        # One line-sized HBM->VMEM DMA per dereference (the paper's single
-        # memory transaction), started at the chased offset.
-        cp = pltpu.make_async_copy(
-            a_ref.at[pl.ds(j, line_ref.shape[0])], line_ref, sem)
+        # One row-sized HBM->SMEM DMA per dereference (the paper's single
+        # memory transaction): the aligned row that holds index j.
+        cp = pltpu.make_async_copy(a_ref.at[pl.ds(j // LANES, 1)],
+                                   line_ref, sem)
         cp.start()
         cp.wait()
-        nj = line_ref[0]
+        nj = line_ref[0, j % LANES]
         o_ref[t] = nj
         return nj
 
     jax.lax.fori_loop(0, o_ref.shape[0], body, start_ref[0], unroll=False)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("iterations", "line_elems", "interpret"))
+@functools.partial(jax.jit, static_argnames=("iterations", "interpret"))
 def pchase_trace(array: jax.Array, start: jax.Array | int = 0, *,
-                 iterations: int, line_elems: int = 8,
-                 interpret: bool = True) -> jax.Array:
-    """Run the chase; returns the int32 index trace (length `iterations`).
-
-    ``line_elems=8`` ⇒ 32-byte lines, matching the caches the paper probes.
-    The array must be padded so every chased load has `line_elems` headroom.
-    """
+                 iterations: int, interpret: bool | None = None
+                 ) -> jax.Array:
+    """Run the chase; returns the int32 index trace (length `iterations`)."""
     n = array.shape[0]
-    padded = jnp.concatenate(
-        [array.astype(jnp.int32),
-         jnp.zeros((line_elems,), jnp.int32)])
+    rows = -(-n // LANES)
+    table = jnp.pad(array.astype(jnp.int32),
+                    (0, rows * LANES - n)).reshape(rows, LANES)
     start = jnp.asarray(start, jnp.int32).reshape((1,))
     return pl.pallas_call(
         _pchase_kernel,
@@ -62,12 +66,12 @@ def pchase_trace(array: jax.Array, start: jax.Array | int = 0, *,
             pl.BlockSpec(memory_space=pltpu.SMEM),   # start index (scalar)
             pl.BlockSpec(memory_space=pl.ANY),       # chase array in HBM
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((iterations,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((line_elems,), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((1, LANES), jnp.int32),
                         pltpu.SemaphoreType.DMA],
-        interpret=interpret,
-    )(start, padded)
+        interpret=resolve_interpret(interpret),
+    )(start, table)
 
 
 def uniform_init(num_elems: int, stride_elems: int) -> jax.Array:
@@ -106,7 +110,7 @@ def chase_array_from_indices(indices, num_elems: int):
     return jnp.asarray(arr)
 
 
-def pallas_trace_backend(*, line_elems: int = 8, interpret: bool = True,
+def pallas_trace_backend(*, interpret: bool | None = None,
                          repeats: int = 2):
     """A :class:`repro.core.pchase.TraceBackend` driving the Pallas kernel.
 
@@ -130,7 +134,7 @@ def pallas_trace_backend(*, line_elems: int = 8, interpret: bool = True,
     def _timed_chase(arr: jax.Array, start: int, iters: int) -> tuple:
         t0 = time.perf_counter()
         out = pchase_trace(arr, start, iterations=iters,
-                           line_elems=line_elems, interpret=interpret)
+                           interpret=interpret)
         out.block_until_ready()
         return np.asarray(out), time.perf_counter() - t0
 
@@ -168,6 +172,6 @@ def pallas_trace_backend(*, line_elems: int = 8, interpret: bool = True,
         return PChaseTrace(config, rec[:k], lat,
                            meta={"timing": "differential",
                                  "per_access_ns": per_access_ns,
-                                 "interpret": interpret})
+                                 "interpret": resolve_interpret(interpret)})
 
     return run

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -23,7 +25,8 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jax.Array:
+            block_rows: int = 256, interpret: bool | None = None
+            ) -> jax.Array:
     """x: (rows, d); scale: (d,)."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)
@@ -36,5 +39,5 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale)

@@ -10,6 +10,11 @@ same physics appears when a VMEM gather makes one *lane* serve many rows:
 semantics to the model, validated against ``ref.strided_ref`` and — on real
 hardware — timed across strides to reproduce the Table 8 latency-vs-ways
 curve for VMEM.
+
+The gather is a serial loop of one-row VMEM copies at a dynamic sublane
+offset: Mosaic lowers no in-kernel vector gather.  Dynamic row offsets
+need 32-bit rows on the chip (packed dtypes want offsets a multiple of
+their packing).
 """
 
 from __future__ import annotations
@@ -17,24 +22,30 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _strided_kernel(x_ref, o_ref, *, stride: int):
     n = x_ref.shape[0]
-    idx = (jax.lax.iota(jnp.int32, n) * stride) % n
-    o_ref[...] = jnp.take(x_ref[...], idx, axis=0)
+
+    def row(i, carry):
+        src = jax.lax.rem(i * stride, n)
+        o_ref[pl.ds(i, 1), :] = x_ref[pl.ds(src, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, n, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret"))
 def strided_gather(x: jax.Array, *, stride: int,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """out[i] = x[(i * stride) % n] over the leading axis, in one VMEM block."""
     return pl.pallas_call(
         functools.partial(_strided_kernel, stride=stride),
         in_specs=[pl.BlockSpec(x.shape, lambda: (0,) * x.ndim)],
         out_specs=pl.BlockSpec(x.shape, lambda: (0,) * x.ndim),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

@@ -14,6 +14,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -30,7 +31,18 @@ def make_production_mesh(*, multi_pod: bool = False,
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count for dry-runs")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    """A mesh whose axes are all ``Auto``: shardings are placement hints
+    that the compiler propagates (``with_sharding_constraint`` and the
+    logical rule table), not part of array types.  ``jax.make_mesh``
+    defaults to ``Explicit`` axes, under which a gather from a sharded
+    table or a matmul contracting a sharded dimension must name its output
+    sharding at every call site."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_serve_mesh(shape: "int | tuple[int, ...] | None" = None, *,
@@ -65,4 +77,4 @@ def make_serve_mesh(shape: "int | tuple[int, ...] | None" = None, *,
             f"serve mesh {shape} needs {need} devices, have {len(devices)}"
             f" — set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{need} (before jax initializes) for a host-device mesh")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])

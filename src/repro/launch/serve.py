@@ -71,7 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import configs, jaxcache
 from repro.models import transformer as T
 from repro.train.loop import make_serve_step
 
@@ -586,6 +586,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    # jax is already imported here, so the cache is set through its config
+    # (the JAX_* variables that enable_env sets are read at import)
+    jaxcache.enable()
     if args.router_margin is None:
         from repro.serve.fleet import ROUTER_MARGIN
         args.router_margin = ROUTER_MARGIN

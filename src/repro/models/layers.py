@@ -18,7 +18,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels import ops as kops
@@ -165,7 +164,7 @@ def _paged_scatter(pages: jax.Array, page_table: jax.Array,
     if sharded is None:
         return _paged_scatter_impl(pages, page_table, positions, vals)
     ctx, ax = sharded
-    return shard_map(
+    return jax.shard_map(
         _paged_scatter_impl, mesh=ctx.mesh,
         in_specs=(P(None, None, ax, None), P(None, None), P(None, None),
                   P(None, None, ax, None)),
@@ -186,7 +185,7 @@ def _paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     sharded = _paged_shard_axes(pages)
     if sharded is not None:
         ctx, ax = sharded
-        g = shard_map(
+        g = jax.shard_map(
             _paged_gather_impl, mesh=ctx.mesh,
             in_specs=(P(None, None, ax, None), P(None, None)),
             out_specs=P(None, None, ax, None))(pages, page_table)

@@ -18,8 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh,
@@ -30,6 +29,12 @@ def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh,
     x_micro:      (M, micro_batch, ...) microbatched input.
     Returns       (M, micro_batch, ...) outputs (stage order preserved).
     """
+    # the stage axis is manual inside shard_map; outside it the result is
+    # an ordinary auto-sharded array, so callers can differentiate through
+    # it without entering the mesh (jax.make_mesh defaults to explicit
+    # axes, whose typed results need an ambient `jax.set_mesh`)
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     num_stages = mesh.shape[stage_axis]
     num_micro = x_micro.shape[0]
     ticks = num_micro + num_stages - 1
@@ -68,11 +73,11 @@ def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh,
         # a psum replicates the result to every stage
         return jax.lax.psum(outputs, stage_axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x_micro)
 
 

@@ -224,6 +224,11 @@ class PagedServeEngine:
             rules = dict(MESH_SERVE_RULES)
             rules.update(shard_rules or {})
             self._shard_ctx = sharding.ShardingCtx(mesh, rules)
+            # every device of the mesh holds the whole model: replicate the
+            # weights once here (a no-op when they already are), or each
+            # step would copy them onto the mesh again
+            self.params = jax.device_put(
+                params, NamedSharding(mesh, PartitionSpec()))
         else:
             self._shard_ctx = None
         self.shards = paging.gather_shards(cfg, self._shard_ctx)

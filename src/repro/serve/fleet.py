@@ -82,6 +82,9 @@ import dataclasses
 from collections import deque
 from typing import Callable, Sequence
 
+import jax
+from jax.sharding import NamedSharding, PartitionSpec
+
 from repro.core import littles_law, profile
 from repro.core.costmodel import (ParallelismPlan, decode_cell_cost,
                                   prefill_cell_cost)
@@ -379,6 +382,11 @@ class FleetEngine:
             pools = list(num_pages)
         else:
             pools = [num_pages] * len(profiles)
+        if mesh is not None:
+            # one replicated copy of the weights shared by every replica
+            # (each engine's own replication is then a no-op)
+            params = jax.device_put(params, NamedSharding(mesh,
+                                                          PartitionSpec()))
         self.cfg = cfg
         self.margin = margin
         self.migration = migration

@@ -1,0 +1,132 @@
+"""Compile the main path's kernels and serving steps for a described TPU v5e
+chip, with no chip attached.
+
+The TPU compiler refuses what interpret mode accepts: scalar stores to
+VMEM, unaligned DMA slices, in-kernel gathers, programs larger than the
+chip's memory.  Each test here lowers with ``interpret=False`` and
+compiles against one chip of a described ``v5e:2x2`` topology, so such a
+refusal fails the suite instead of a run on the chip.  The topology is
+described inside a fixture, never while a module is imported, and the
+persistent compilation cache is off around these compiles (an entry
+compiled for a described chip cannot be read back without one).
+"""
+
+import dataclasses
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs, kernels
+from repro.kernels import dbuf_copy, flash_attention, memcpy, pchase, \
+    rmsnorm, strided
+from repro.models import transformer as T
+
+INTERNVL2 = configs.get_config("internvl2-2b")
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+_HEADS, _KV_HEADS, _HEAD_DIM = (INTERNVL2.num_heads, INTERNVL2.num_kv_heads,
+                                INTERNVL2.head_dim)
+
+#: name -> (kernel called with interpret=False, argument shapes and dtypes)
+KERNELS = {
+    "memcpy": (functools.partial(memcpy.memcpy, block_rows=256),
+               [((4096, 512), jnp.float32)]),
+    "rmsnorm": (rmsnorm.rmsnorm,
+                [((4096, INTERNVL2.d_model), jnp.bfloat16),
+                 ((INTERNVL2.d_model,), jnp.bfloat16)]),
+    "dbuf_copy": (functools.partial(dbuf_copy.dbuf_copy, block_rows=256,
+                                    num_buffers=2),
+                  [((4096, 512), jnp.float32)]),
+    # internvl2-2b's head geometry: 16 q / 8 kv heads of 128, S = 2048
+    "flash_attention": (
+        functools.partial(flash_attention.flash_attention,
+                          num_q_heads=_HEADS, num_kv_heads=_KV_HEADS),
+        [((_HEADS, 2048, _HEAD_DIM), jnp.bfloat16),
+         ((_KV_HEADS, 2048, _HEAD_DIM), jnp.bfloat16),
+         ((_KV_HEADS, 2048, _HEAD_DIM), jnp.bfloat16)]),
+    "pchase": (functools.partial(pchase.pchase_trace, iterations=4096),
+               [((1 << 20,), jnp.int32)]),
+    "strided": (functools.partial(strided.strided_gather, stride=6),
+                [((512, 128), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_compiles_with_mosaic(name, one_chip):
+    fn, shapes = KERNELS[name]
+    args = [_spec(s, d, one_chip) for s, d in shapes]
+    compiled = jax.jit(functools.partial(fn, interpret=False)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_interpret_default_follows_backend(monkeypatch):
+    assert kernels.resolve_interpret(None) is (
+        jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernels.resolve_interpret(None) is False
+    assert kernels.resolve_interpret(True) is True
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_paged_step_fits_one_chip(step, one_chip):
+    """internvl2-2b at its published widths (2 of its 24 layers, for test
+    time) in the rehearsed pool geometry: 8 slots of 4096 tokens, pages
+    of 128, 257 pages.  The step is jitted as PagedServeEngine jits it,
+    with the pool donated."""
+    cfg = dataclasses.replace(INTERNVL2, num_layers=2)
+    slots, pages_per_seq, page_len, num_pages = 8, 32, 128, 257
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(T.init_params, cfg),
+                                    jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, num_pages, page_len, slots)))
+    batch, s = (slots, 1) if step == "decode" else (1, page_len)
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    def step_fn(p, c, t, st, tab, sl, sq):
+        return T.paged_step(p, cfg, c, t, st, tab, sl, sq)
+
+    seq_lens = None if step == "decode" else i32(batch)
+    compiled = jax.jit(step_fn, donate_argnums=1).lower(
+        params, cache, i32(batch, s), i32(batch), i32(batch, pages_per_seq),
+        i32(batch), seq_lens).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.alias_size_in_bytes > 0, "the pool is not donated"
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
