@@ -1,0 +1,17 @@
+"""Order statistics used by the metric readers."""
+
+from __future__ import annotations
+
+import math
+
+
+def percentile(values, q: float) -> float:
+    """Nearest-rank percentile: the ``ceil(q/100 * n)``-th smallest value
+    of all the samples.  Always one of ``values``; no interpolation."""
+    if not 0 < q <= 100:
+        raise ValueError(f"percentile q must be in (0, 100], got {q}")
+    vals = sorted(values)
+    if not vals:
+        raise ValueError("percentile of an empty sequence")
+    return float(vals[math.ceil(q / 100.0 * len(vals)) - 1])
+
