@@ -60,11 +60,17 @@ batching engines, or the multi-replica fleet over a synthetic workload.
   python -m repro.launch.serve --arch granite-8b --smoke --engine fleet \
       --fleet-profiles tpu_v5e,TeslaV100 \
       --fleet-tiers prefill:0/decode:1 --requests 16
+
+  # any of the above under the JAX profiler: device ops beside the
+  # serving loop's serve.* host spans, in DIR/plugins/profile/<run>/
+  python -m repro.launch.serve --arch granite-8b --smoke --engine fleet \
+      --requests 8 --trace-dir /tmp/serve-trace
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -581,6 +587,12 @@ def build_parser() -> argparse.ArgumentParser:
                          "predicted step cost compete on page headroom "
                          "(default: serve.fleet.ROUTER_MARGIN)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-dir", metavar="DIR", default=None,
+                    help="record the served run with the JAX profiler "
+                         "into DIR: device ops beside the serve.* host "
+                         "spans (repro.serve.spans); open the .xplane.pb "
+                         "under DIR/plugins/profile/ in TensorBoard or "
+                         "read it with jax.profiler.ProfileData")
     return ap
 
 
@@ -613,17 +625,19 @@ def main(argv=None):
         _plan(cfg, args)       # pure accounting: no params, no device
         return
     params = T.init_params(cfg, jax.random.key(0))
-    if args.engine == "loop":
-        _batch_loop(cfg, params, args)
-    elif args.engine == "fleet":
-        if args.faults is not None:
-            _fault_campaign(cfg, params, args)
-        elif args.workload is not None:
-            _workload_run(cfg, params, args)
+    with (jax.profiler.trace(args.trace_dir) if args.trace_dir
+          else contextlib.nullcontext()):
+        if args.engine == "loop":
+            _batch_loop(cfg, params, args)
+        elif args.engine == "fleet":
+            if args.faults is not None:
+                _fault_campaign(cfg, params, args)
+            elif args.workload is not None:
+                _workload_run(cfg, params, args)
+            else:
+                _fleet_run(cfg, params, args)
         else:
-            _fleet_run(cfg, params, args)
-    else:
-        _engine_run(cfg, params, args)
+            _engine_run(cfg, params, args)
 
 
 if __name__ == "__main__":

@@ -13,6 +13,7 @@ annotations so pjit propagates the intended DP/TP/EP/SP layout.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -74,6 +75,20 @@ PARAM_AXES: dict[str, tuple[str | None, ...]] = {
 def _init(key, shape, scale_dim, dtype):
     return (jax.random.normal(key, shape, jnp.float32)
             * (scale_dim ** -0.5)).astype(dtype)
+
+
+def scoped(name: str):
+    """Trace the decorated function inside ``jax.named_scope(name)``, so
+    its ops carry ``name`` in their compiled ``op_name`` metadata.
+    Metadata only: the compiled program is the same.  The scope is looked
+    up at call time, so a test can trace without it."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return inner
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -147,6 +162,7 @@ def _paged_shard_axes(pages: jax.Array):
     return ctx, heads_ax
 
 
+@scoped("kv_write")
 def _paged_scatter(pages: jax.Array, page_table: jax.Array,
                    positions: jax.Array, vals: jax.Array) -> jax.Array:
     """Write per-token values into the shared page pool.
@@ -172,6 +188,7 @@ def _paged_scatter(pages: jax.Array, page_table: jax.Array,
                                            vals)
 
 
+@scoped("kv_gather")
 def _paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """Gather each slot's pages back into a (B, P*page_len, ...) view.
 
@@ -216,6 +233,7 @@ def init_attention(key, cfg: ModelConfig) -> dict:
     }
 
 
+@scoped("attention")
 def _sdpa(q, k, v, cfg: ModelConfig, *, causal: bool,
           kv_len_mask: jax.Array | None = None) -> jax.Array:
     """q: (B,S,H,D); k/v: (B,T,Hkv,D).  kv_len_mask: (B,T) valid-slot mask
@@ -499,6 +517,7 @@ def apply_ffn(p: dict, x: jax.Array, cfg: ModelConfig,
     return (h @ p[n("w_down")]).astype(x.dtype)
 
 
+@scoped("mlp")
 def apply_dense_block(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     xn = rms_norm(x, p["ffn_norm"], cfg.norm_eps)
     return x + apply_ffn(p, xn, cfg)

@@ -224,6 +224,7 @@ def rms_final(params, cfg, x):
     return L.rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
+@L.scoped("head")
 def head_logits(params, cfg, x):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = jnp.einsum("bsd,dv->bsv", x.astype(jnp.float32),

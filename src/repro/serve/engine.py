@@ -38,12 +38,13 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.parallel import sharding
-from repro.serve import paging
+from repro.serve import paging, spans
 from repro.serve.paging import OutOfPages, PageAllocator
 
 #: rule overrides for a serving mesh: ONLY the paged pool shards (KV
@@ -424,39 +425,45 @@ class PagedServeEngine:
                and self.alloc.free_pages
                >= self.alloc.pages_for(self.prefill_chunk)):
             req = self.waiting.popleft()
-            req.slot = self.free_slots.popleft()
-            if req.admit_seq < 0:      # preempted requests keep seniority
-                req.admit_seq = self._admit_counter
-                self._admit_counter += 1
-            req.prefill_pos = 0
-            req.generated = []
-            self.page_tables[req.slot][:] = 0
-            self.positions[req.slot] = 0
-            self.last_tokens[req.slot] = 0
-            self.prefilling.append(req)
+            with TraceAnnotation(spans.ADMITTED, uid=req.uid):
+                req.slot = self.free_slots.popleft()
+                if req.admit_seq < 0:  # preempted requests keep seniority
+                    req.admit_seq = self._admit_counter
+                    self._admit_counter += 1
+                req.prefill_pos = 0
+                req.generated = []
+                self.page_tables[req.slot][:] = 0
+                self.positions[req.slot] = 0
+                self.last_tokens[req.slot] = 0
+                self.prefilling.append(req)
 
     def _prefill_tick(self) -> None:
         """One page-sized chunk of the oldest prefilling request."""
         req = self.prefilling[0]
         plen = len(req.prompt)
-        start = req.prefill_pos
-        # the chunk's padded tail writes garbage up to the chunk boundary,
-        # so pages must cover it (chunk = 1 page by default -> <=1 page of
-        # slack, reclaimed as decode writes fill the tail back in)
-        if not self._ensure_pages(req, start + self.prefill_chunk):
-            return                      # stall; decode ticks will free pages
-        s_real = min(self.prefill_chunk, plen - start)
-        toks = np.zeros(self.prefill_chunk, dtype=np.int32)
-        toks[:s_real] = req.prompt[start:start + s_real]
-        logits, self.cache = self._chunk_step(
-            self.params, self.cache, jnp.asarray(toks[None]),
-            jnp.asarray([start], jnp.int32),
-            jnp.asarray(self.page_tables[req.slot][None]),
-            jnp.asarray([req.slot], jnp.int32),
-            jnp.asarray([s_real], jnp.int32))
-        req.prefill_pos += s_real
-        if req.prefill_pos == plen:
+        with TraceAnnotation(spans.PREFILL):
+            start = req.prefill_pos
+            # the chunk's padded tail writes garbage up to the chunk
+            # boundary, so pages must cover it (chunk = 1 page by default
+            # -> <=1 page of slack, reclaimed as decode writes fill the
+            # tail back in)
+            if not self._ensure_pages(req, start + self.prefill_chunk):
+                return                  # stall; decode ticks will free pages
+            s_real = min(self.prefill_chunk, plen - start)
+            toks = np.zeros(self.prefill_chunk, dtype=np.int32)
+            toks[:s_real] = req.prompt[start:start + s_real]
+            logits, self.cache = self._chunk_step(
+                self.params, self.cache, jnp.asarray(toks[None]),
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray(self.page_tables[req.slot][None]),
+                jnp.asarray([req.slot], jnp.int32),
+                jnp.asarray([s_real], jnp.int32))
+            req.prefill_pos += s_real
+        if req.prefill_pos < plen:
+            return
+        with TraceAnnotation(spans.SYNC):
             tok = int(np.asarray(self.sampler(logits[0, s_real - 1])))
+        with TraceAnnotation(spans.COMMIT):
             req.generated.append(tok)
             self.last_tokens[req.slot] = tok
             self.positions[req.slot] = plen
@@ -479,32 +486,41 @@ class PagedServeEngine:
             self.free_slots.append(slot)
             self.finished.append(req)
 
-    def _decode_tick(self) -> None:
-        # grow every decoding request to cover its next write position; a
-        # request that cannot get a page even after preempting younger
-        # work rolls itself back
-        for slot in sorted(self.active):
-            req = self.active.get(slot)
-            if req is None:
-                continue               # preempted by an earlier slot's grow
-            if not self._ensure_pages(req, int(self.positions[slot]) + 1):
-                self._preempt(req)
-        if not self.active:
-            return
-        # batch rows without a DECODING request (free slots, but also slots
-        # still mid-prefill) are retargeted at the scratch page / scratch
-        # slot row so their garbage writes cannot corrupt live state
-        mask = np.zeros(self.max_slots, dtype=bool)
-        mask[list(self.active)] = True
-        tables = np.where(mask[:, None], self.page_tables, 0)
-        slot_ids = np.where(mask, np.arange(self.max_slots), self.max_slots)
-        toks = jnp.asarray(self.last_tokens[:, None], jnp.int32)
-        logits, self.cache = self._decode_step(
-            self.params, self.cache, toks,
-            jnp.asarray(self.positions, jnp.int32),
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(slot_ids, jnp.int32))
-        sampled = np.asarray(self.sampler(logits[:, 0]))
+    def _decode_tick(self) -> np.ndarray | None:
+        """Dispatch one batched decode step and return every row's sampled
+        token, or None when no request decodes."""
+        with TraceAnnotation(spans.DECODE):
+            # grow every decoding request to cover its next write position;
+            # a request that cannot get a page even after preempting
+            # younger work rolls itself back
+            for slot in sorted(self.active):
+                req = self.active.get(slot)
+                if req is None:
+                    continue           # preempted by an earlier slot's grow
+                if not self._ensure_pages(req,
+                                          int(self.positions[slot]) + 1):
+                    self._preempt(req)
+            if not self.active:
+                return None
+            # batch rows without a DECODING request (free slots, but also
+            # slots still mid-prefill) are retargeted at the scratch page /
+            # scratch slot row so their garbage writes cannot corrupt live
+            # state
+            mask = np.zeros(self.max_slots, dtype=bool)
+            mask[list(self.active)] = True
+            tables = np.where(mask[:, None], self.page_tables, 0)
+            slot_ids = np.where(mask, np.arange(self.max_slots),
+                                self.max_slots)
+            toks = jnp.asarray(self.last_tokens[:, None], jnp.int32)
+            logits, self.cache = self._decode_step(
+                self.params, self.cache, toks,
+                jnp.asarray(self.positions, jnp.int32),
+                jnp.asarray(tables, jnp.int32),
+                jnp.asarray(slot_ids, jnp.int32))
+        with TraceAnnotation(spans.SYNC):
+            return np.asarray(self.sampler(logits[:, 0]))
+
+    def _commit(self, sampled: np.ndarray) -> None:
         for slot, req in list(self.active.items()):
             tok = int(sampled[slot])
             req.generated.append(tok)
@@ -516,12 +532,16 @@ class PagedServeEngine:
     def step(self) -> int:
         """Admit + at most one prefill chunk + one batched decode step.
         Returns the number of live (prefilling or decoding) requests."""
-        self._admit()
+        with TraceAnnotation(spans.ADMIT):
+            self._admit()
         if self.prefilling:
             self._prefill_tick()
-        self._decode_tick()
-        self.steps += 1
-        self._record_slack()
+        sampled = self._decode_tick()
+        with TraceAnnotation(spans.COMMIT):
+            if sampled is not None:
+                self._commit(sampled)
+            self.steps += 1
+            self._record_slack()
         return len(self.active) + len(self.prefilling) + len(self.ready)
 
     def cancel(self, uid: int) -> bool:

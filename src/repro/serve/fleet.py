@@ -83,6 +83,7 @@ from collections import deque
 from typing import Callable, Sequence
 
 import jax
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import littles_law, profile
@@ -90,7 +91,7 @@ from repro.core.costmodel import (ParallelismPlan, decode_cell_cost,
                                   prefill_cell_cost)
 from repro.core.devices import TpuSpec
 from repro.models.config import ModelConfig
-from repro.serve import paging, tiers as tiering
+from repro.serve import paging, spans, tiers as tiering
 from repro.serve.engine import PagedServeEngine, Request
 from repro.serve.tiers import TierPlan
 
@@ -821,7 +822,8 @@ class FleetEngine:
         self._readmit_due()
         if self._transit:
             self._arrive_handoffs()
-        self._dispatch()
+        with TraceAnnotation(spans.ROUTE):
+            self._dispatch()
         for r in self.replicas:
             if r.dispatchable:
                 r.engine.step()

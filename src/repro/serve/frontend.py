@@ -47,7 +47,9 @@ import dataclasses
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.serve import spans
 from repro.serve.engine import Request
 from repro.serve.fleet import FleetEngine
 from repro.serve.slo import SLOTracker
@@ -123,16 +125,17 @@ class FleetFrontend:
             uid = self._next_uid
         if uid in self.handles:
             raise ValueError(f"uid {uid} already submitted")
-        req = Request(uid, np.asarray(prompt, dtype=np.int32),
-                      max_new_tokens)
-        self.fleet.submit(req)          # may raise ValueError: unservable
-        # bookkeeping only after the fleet accepted the request — a
-        # rejected submission must not burn a uid or leave a handle
-        self._next_uid = max(self._next_uid, uid) + 1
-        handle = StreamHandle(uid, req, on_token, on_finish)
-        self.handles[uid] = handle
-        self.slo.on_submit(uid, self.fleet.ticks if arrival_tick is None
-                           else arrival_tick)
+        with TraceAnnotation(spans.SUBMIT, uid=uid):
+            req = Request(uid, np.asarray(prompt, dtype=np.int32),
+                          max_new_tokens)
+            self.fleet.submit(req)      # may raise ValueError: unservable
+            # bookkeeping only after the fleet accepted the request — a
+            # rejected submission must not burn a uid or leave a handle
+            self._next_uid = max(self._next_uid, uid) + 1
+            handle = StreamHandle(uid, req, on_token, on_finish)
+            self.handles[uid] = handle
+            self.slo.on_submit(uid, self.fleet.ticks if arrival_tick is None
+                               else arrival_tick)
         return handle
 
     def submit_blocking(self, prompt, max_new_tokens: int, *,
@@ -208,7 +211,8 @@ class FleetFrontend:
         """One event-loop turn: fleet step + stream drain.  Returns the
         number of live (unsettled) handles."""
         self.fleet.step()
-        self._drain_streams()
+        with TraceAnnotation(spans.DRAIN):
+            self._drain_streams()
         return sum(1 for h in self.handles.values() if not h.settled)
 
     def run(self, max_ticks: int = 10_000) -> list[StreamHandle]:

@@ -11,9 +11,11 @@ persistent compilation cache is off around these compiles (an entry
 compiled for a described chip cannot be read back without one).
 """
 
+import contextlib
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,12 +98,11 @@ def test_interpret_default_follows_backend(monkeypatch):
     assert kernels.resolve_interpret(True) is True
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
-def test_paged_step_fits_one_chip(step, one_chip):
+def _paged_step(step, one_chip):
     """internvl2-2b at its published widths (2 of its 24 layers, for test
     time) in the rehearsed pool geometry: 8 slots of 4096 tokens, pages
     of 128, 257 pages.  The step is jitted as PagedServeEngine jits it,
-    with the pool donated."""
+    with the pool donated; returns it compiled."""
     cfg = dataclasses.replace(INTERNVL2, num_layers=2)
     slots, pages_per_seq, page_len, num_pages = 8, 32, 128, 257
 
@@ -122,11 +123,42 @@ def test_paged_step_fits_one_chip(step, one_chip):
         return T.paged_step(p, cfg, c, t, st, tab, sl, sq)
 
     seq_lens = None if step == "decode" else i32(batch)
-    compiled = jax.jit(step_fn, donate_argnums=1).lower(
+    return jax.jit(step_fn, donate_argnums=1).lower(
         params, cache, i32(batch, s), i32(batch), i32(batch, pages_per_seq),
         i32(batch), seq_lens).compile()
-    mem = compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_paged_step_fits_one_chip(step, one_chip):
+    mem = _paged_step(step, one_chip).memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes > 0, "the pool is not donated"
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
+
+
+#: headers of the HLO text's debug tables, whose rows start with an id
+_DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                 "StackFrames")
+
+
+def _strip_metadata(hlo: str) -> str:
+    """The HLO text without op metadata and the source tables it points
+    into: what is left is the program."""
+    lines = [ln for ln in hlo.splitlines()
+             if ln not in _DEBUG_TABLES and not re.match(r"\d+ ", ln)]
+    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_named_scopes_change_op_metadata_only(step, one_chip, monkeypatch):
+    """The step's parts carry their scope in ``op_name``; without the
+    scopes the chip's compiler makes the same program, op for op."""
+    scoped = _paged_step(step, one_chip).as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _paged_step(step, one_chip).as_text()
+    for scope in ("kv_write", "kv_gather", "attention", "mlp", "head"):
+        assert f"/{scope}/" in scoped, scope
+        assert f"/{scope}/" not in plain, scope
+    assert _strip_metadata(scoped) == _strip_metadata(plain)
