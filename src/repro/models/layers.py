@@ -131,46 +131,52 @@ def _decode_valid(t: int, cache_index) -> jax.Array:
 # -- paged KV cache (repro.serve.paging) -------------------------------------
 
 
-def _paged_scatter_impl(pages: jax.Array, page_table: jax.Array,
-                        positions: jax.Array, vals: jax.Array) -> jax.Array:
-    pl = pages.shape[1]
+def _paged_scatter_impl(pages: jax.Array, layer: jax.Array,
+                        page_table: jax.Array, positions: jax.Array,
+                        vals: jax.Array) -> jax.Array:
+    pl = pages.shape[2]
     phys = jnp.take_along_axis(page_table, positions // pl, axis=1)
-    return pages.at[phys, positions % pl].set(vals.astype(pages.dtype))
+    return pages.at[layer, phys, positions % pl].set(vals.astype(pages.dtype))
 
 
-def _paged_gather_impl(pages: jax.Array, page_table: jax.Array) -> jax.Array:
+def _paged_gather_impl(pages: jax.Array, layer: jax.Array,
+                       page_table: jax.Array) -> jax.Array:
     b, p = page_table.shape
-    g = pages[page_table]
-    return g.reshape(b, p * pages.shape[1], *pages.shape[2:])
+    g = pages[layer, page_table]
+    return g.reshape(b, p * pages.shape[2], *pages.shape[3:])
 
 
 def _paged_shard_axes(pages: jax.Array):
     """(ctx, heads_mesh_axes) when the shard_map fast path applies to this
-    pool leaf — an active sharding ctx whose rules put the KV-heads dim on
-    present mesh axes (divisibly; the GQA fallback drops it otherwise)
-    while pages and head_dim stay whole.  None -> plain impl: unsharded
-    engines, MLA's rank-3 compressed leaves, and pages-on-"data" layouts
-    (GSPMD handles the cross-shard gather there)."""
+    stacked pool leaf — an active sharding ctx whose rules put the
+    KV-heads dim on present mesh axes (divisibly; the GQA fallback drops
+    it otherwise) while layers, pages and head_dim stay whole.  None ->
+    plain impl: unsharded engines, MLA's compressed leaves, and
+    pages-on-"data" layouts (GSPMD handles the cross-shard gather there)."""
     ctx = sharding.current()
-    if ctx is None or pages.ndim != 4:
+    if ctx is None or pages.ndim != 5:
         return None
-    spec = tuple(ctx.spec(("cache_pages", None, "cache_kv_heads",
+    spec = tuple(ctx.spec(("layers", "cache_pages", None, "cache_kv_heads",
                            "cache_head_dim"), pages.shape))
-    pages_ax, _, heads_ax, hd_ax = spec
-    if not heads_ax or pages_ax or hd_ax:
+    layers_ax, pages_ax, _, heads_ax, hd_ax = spec
+    if not heads_ax or layers_ax or pages_ax or hd_ax:
         return None
     return ctx, heads_ax
 
 
 @scoped("kv_write")
-def _paged_scatter(pages: jax.Array, page_table: jax.Array,
-                   positions: jax.Array, vals: jax.Array) -> jax.Array:
-    """Write per-token values into the shared page pool.
+def _paged_scatter(pages: jax.Array, layer: jax.Array,
+                   page_table: jax.Array, positions: jax.Array,
+                   vals: jax.Array) -> jax.Array:
+    """Write per-token values into one layer's rows of the shared pool.
 
-    pages: (num_pages, page_len, ...); page_table: (B, P) physical page of
-    each logical page; positions: (B, S) absolute token positions; vals:
-    (B, S, ...).  Inactive slots point at the scratch page (0), so their
-    garbage writes can never land in a live request's pages.
+    pages: (layers, num_pages, page_len, ...), every layer's pool stacked;
+    layer: the () index of the layer written; page_table: (B, P) physical
+    page of each logical page; positions: (B, S) absolute token positions;
+    vals: (B, S, ...).  Inactive slots point at the scratch page (0), so
+    their garbage writes can never land in a live request's pages.  The
+    scatter is indexed by ``layer`` itself, so no layer's pool is sliced
+    out and written back: the stacked leaf updates in place.
 
     Under a serving mesh the heads-sharded pool updates per shard via
     ``shard_map``: each shard scatters only its own heads slice (no
@@ -178,19 +184,21 @@ def _paged_scatter(pages: jax.Array, page_table: jax.Array,
     the update is in-place on every shard)."""
     sharded = _paged_shard_axes(pages)
     if sharded is None:
-        return _paged_scatter_impl(pages, page_table, positions, vals)
+        return _paged_scatter_impl(pages, layer, page_table, positions, vals)
     ctx, ax = sharded
     return jax.shard_map(
         _paged_scatter_impl, mesh=ctx.mesh,
-        in_specs=(P(None, None, ax, None), P(None, None), P(None, None),
-                  P(None, None, ax, None)),
-        out_specs=P(None, None, ax, None))(pages, page_table, positions,
-                                           vals)
+        in_specs=(P(None, None, None, ax, None), P(), P(None, None),
+                  P(None, None), P(None, None, ax, None)),
+        out_specs=P(None, None, None, ax, None))(pages, layer, page_table,
+                                                 positions, vals)
 
 
 @scoped("kv_gather")
-def _paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """Gather each slot's pages back into a (B, P*page_len, ...) view.
+def _paged_gather(pages: jax.Array, layer: jax.Array,
+                  page_table: jax.Array) -> jax.Array:
+    """Gather each slot's pages of one layer into a (B, P*page_len, ...)
+    view, read straight from the stacked pool (see :func:`_paged_scatter`).
 
     The sharded path gathers per shard (each shard reads its own heads
     slice at its own partition's bandwidth — the per-partition pricing
@@ -204,11 +212,11 @@ def _paged_gather(pages: jax.Array, page_table: jax.Array) -> jax.Array:
         ctx, ax = sharded
         g = jax.shard_map(
             _paged_gather_impl, mesh=ctx.mesh,
-            in_specs=(P(None, None, ax, None), P(None, None)),
-            out_specs=P(None, None, ax, None))(pages, page_table)
+            in_specs=(P(None, None, None, ax, None), P(), P(None, None)),
+            out_specs=P(None, None, ax, None))(pages, layer, page_table)
     else:
         ctx = sharding.current()
-        g = _paged_gather_impl(pages, page_table)
+        g = _paged_gather_impl(pages, layer, page_table)
     if ctx is not None:
         g = jax.lax.with_sharding_constraint(
             g, NamedSharding(ctx.mesh, P()))
@@ -308,7 +316,8 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
                     positions: jax.Array,
                     cache: dict | None = None,
                     cache_index: jax.Array | None = None,
-                    page_table: jax.Array | None = None
+                    page_table: jax.Array | None = None,
+                    layer: jax.Array | None = None
                     ) -> tuple[jax.Array, dict | None]:
     b, s, d = x.shape
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -327,13 +336,14 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
         o = _sdpa(q, k, v, cfg, causal=causal)
         new_cache = {"k": k, "v": v}
     elif page_table is not None:
-        # paged cache: scatter this step's K/V into the shared page pool,
-        # gather each slot's pages back, mask by absolute position.  Covers
-        # both one-token decode (s=1) and chunked prefill (s=chunk).
-        ck = _paged_scatter(cache["k"], page_table, positions, k)
-        cv = _paged_scatter(cache["v"], page_table, positions, v)
-        kg = _paged_gather(ck, page_table)
-        vg = _paged_gather(cv, page_table)
+        # paged cache: scatter this step's K/V into this layer's rows of
+        # the stacked page pool, gather each slot's pages back, mask by
+        # absolute position.  Covers both one-token decode (s=1) and
+        # chunked prefill (s=chunk).
+        ck = _paged_scatter(cache["k"], layer, page_table, positions, k)
+        cv = _paged_scatter(cache["v"], layer, page_table, positions, v)
+        kg = _paged_gather(ck, layer, page_table)
+        vg = _paged_gather(cv, layer, page_table)
         o = _sdpa(q, kg, vg, cfg, causal=False,
                   kv_len_mask=_paged_valid(kg.shape[1], positions))
         new_cache = {"k": ck, "v": cv}
@@ -405,7 +415,8 @@ def init_mla(key, cfg: ModelConfig) -> dict:
 def apply_mla(p: dict, x: jax.Array, cfg: ModelConfig, *,
               positions: jax.Array, cache: dict | None = None,
               cache_index: jax.Array | None = None,
-              page_table: jax.Array | None = None
+              page_table: jax.Array | None = None,
+              layer: jax.Array | None = None
               ) -> tuple[jax.Array, dict | None]:
     b, s, d = x.shape
     h = cfg.num_heads
@@ -425,12 +436,13 @@ def apply_mla(p: dict, x: jax.Array, cfg: ModelConfig, *,
     valid = None
     if paged:
         # compressed cache lives in the shared page pool (like k/v above)
-        ckv_pages = _paged_scatter(cache["c_kv"], page_table, positions, c_kv)
-        kr_pages = _paged_scatter(cache["k_rope"], page_table, positions,
-                                  k_rope)
+        ckv_pages = _paged_scatter(cache["c_kv"], layer, page_table,
+                                   positions, c_kv)
+        kr_pages = _paged_scatter(cache["k_rope"], layer, page_table,
+                                  positions, k_rope)
         new_cache = {"c_kv": ckv_pages, "k_rope": kr_pages}
-        c_kv = _paged_gather(ckv_pages, page_table)
-        k_rope = _paged_gather(kr_pages, page_table)
+        c_kv = _paged_gather(ckv_pages, layer, page_table)
+        k_rope = _paged_gather(kr_pages, layer, page_table)
         valid = _paged_valid(c_kv.shape[1], positions)
     elif cache is not None:
         if vector_idx:      # continuous batching: per-slot positions

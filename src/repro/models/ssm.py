@@ -146,18 +146,20 @@ def ssd_chunked(x, dt, a_log, b, c, d_skip, chunk: int,
 def apply_ssm(params: dict, xres: jax.Array, cfg: ModelConfig, *,
               cache: dict | None = None, cache_index: jax.Array | None = None,
               slot_ids: jax.Array | None = None,
-              seq_lens: jax.Array | None = None
+              seq_lens: jax.Array | None = None,
+              layer: jax.Array | None = None
               ) -> tuple[jax.Array, dict | None]:
     """Full mamba2 block with residual.  cache = {conv (B,W,Cd), state
     (B,H,N,P)} for one-token decode.
 
-    Paged serving (repro.serve): ``slot_ids`` (B,) selects cache rows to
-    read/update (the SSM state is slot-resident — O(1) per sequence, so it
-    is never paged); a row whose ``cache_index`` is 0 starts fresh (first
-    prefill chunk).  With s>1 this is one *chunked-prefill* step: the SSD
-    recurrence carries the cached state, and ``seq_lens`` (B,) masks the
-    chunk's padded tail (dt=0 ⇒ state-neutral, excluded from the conv
-    window)."""
+    Paged serving (repro.serve): the cache leaves are stacked over layers,
+    ``layer`` is this block's index into them and ``slot_ids`` (B,)
+    selects the rows to read/update (the SSM state is slot-resident — O(1)
+    per sequence, so it is never paged); a row whose ``cache_index`` is 0
+    starts fresh (first prefill chunk).  With s>1 this is one
+    *chunked-prefill* step: the SSD recurrence carries the cached state,
+    and ``seq_lens`` (B,) masks the chunk's padded tail (dt=0 ⇒
+    state-neutral, excluded from the conv window)."""
     bs, s, _ = xres.shape
     d_in, h, p, g, n = _dims(cfg)
     xn = rms_norm(xres, params["ssm_norm"], cfg.norm_eps)
@@ -185,8 +187,8 @@ def apply_ssm(params: dict, xres: jax.Array, cfg: ModelConfig, *,
     else:
         conv_prev, state_prev = cache["conv"], cache["state"]
         if slot_ids is not None:
-            conv_prev = conv_prev[slot_ids]
-            state_prev = state_prev[slot_ids]
+            conv_prev = conv_prev[layer, slot_ids]
+            state_prev = state_prev[layer, slot_ids]
             # a row starting at position 0 is a fresh request: its slot may
             # hold a previous occupant's state, which must not leak in
             fresh = cache_index == 0
@@ -244,9 +246,9 @@ def apply_ssm(params: dict, xres: jax.Array, cfg: ModelConfig, *,
                     wnd, (l, 0), (cfg.ssm_conv - 1, cd)))(win_src, seq_lens)
         if slot_ids is not None:
             new_cache = {
-                "conv": cache["conv"].at[slot_ids].set(
+                "conv": cache["conv"].at[layer, slot_ids].set(
                     new_conv.astype(cache["conv"].dtype)),
-                "state": cache["state"].at[slot_ids].set(new_state)}
+                "state": cache["state"].at[layer, slot_ids].set(new_state)}
         else:
             new_cache = {"conv": new_conv, "state": new_state}
 

@@ -134,16 +134,18 @@ def param_logical_axes(params) -> Any:
 
 def _apply_block(p: dict, x, cfg: ModelConfig, kind: str, ffn: str, *,
                  positions, cache, cache_index, page_table=None,
-                 slot_ids=None, seq_lens=None):
+                 slot_ids=None, seq_lens=None, layer=None):
     aux = jnp.zeros((), jnp.float32)
     if kind == "attn":
         fn = L.apply_mla if cfg.use_mla else L.apply_attention
         x, new_cache = fn(p, x, cfg, positions=positions, cache=cache,
-                          cache_index=cache_index, page_table=page_table)
+                          cache_index=cache_index, page_table=page_table,
+                          layer=layer)
     else:
         x, new_cache = S.apply_ssm(p, x, cfg, cache=cache,
                                    cache_index=cache_index,
-                                   slot_ids=slot_ids, seq_lens=seq_lens)
+                                   slot_ids=slot_ids, seq_lens=seq_lens,
+                                   layer=layer)
     has_ffn = kind == "attn" or cfg.family == "hybrid"
     if has_ffn:
         if ffn == "moe":
@@ -155,7 +157,10 @@ def _apply_block(p: dict, x, cfg: ModelConfig, kind: str, ffn: str, *,
 
 def _apply_unit(unit_params: dict, x, cfg: ModelConfig, *, positions,
                 caches: dict | None, cache_index, page_table=None,
-                slot_ids=None, seq_lens=None):
+                slot_ids=None, seq_lens=None, layer=None):
+    """One scan unit.  ``layer`` is set on the paged path only: ``caches``
+    then holds every unit's stacked pool leaves, and each block reads and
+    writes its own unit's rows of them."""
     spec = unit_spec(cfg)
     new_caches = {}
     aux_total = jnp.zeros((), jnp.float32)
@@ -165,7 +170,7 @@ def _apply_unit(unit_params: dict, x, cfg: ModelConfig, *, positions,
                                   positions=positions, cache=cache_i,
                                   cache_index=cache_index,
                                   page_table=page_table, slot_ids=slot_ids,
-                                  seq_lens=seq_lens)
+                                  seq_lens=seq_lens, layer=layer)
         new_caches[f"b{i}"] = nc
         aux_total = aux_total + aux
     return x, new_caches, aux_total
@@ -334,24 +339,31 @@ def paged_step(params: dict, cfg: ModelConfig, cache: dict,
     unallocated/inactive entries); slot_ids (B,) selects the rows of the
     slot-resident (SSM) cache leaves; seq_lens (B,) counts the valid
     tokens of a padded chunk (None = all valid).  Returns logits for every
-    chunk position, (B, S, vocab)."""
+    chunk position, (B, S, vocab), and the updated cache.
+
+    The whole stacked cache rides the scan's carry: unit ``li`` scatters
+    into and gathers from the rows ``[li, ...]`` of each stacked leaf, so
+    no unit's pool is ever sliced out of the stack or written back into
+    it.  With the cache operand donated the pool updates in place."""
     x = _embed_inputs(params, cfg, {"tokens": tokens})
     b, s, _ = x.shape
     positions = (start[:, None].astype(jnp.int32)
                  + jnp.arange(s, dtype=jnp.int32)[None, :])
 
-    def unit_fn(h, inp):
-        unit_params, unit_cache = inp
-        h, new_cache, _ = _apply_unit(unit_params, h, cfg,
-                                      positions=positions, caches=unit_cache,
-                                      cache_index=start,
-                                      page_table=page_tables,
-                                      slot_ids=slot_ids, seq_lens=seq_lens)
-        return h, new_cache
+    def unit_fn(carry, inp):
+        h, pool = carry
+        unit_params, li = inp
+        h, pool, _ = _apply_unit(unit_params, h, cfg, positions=positions,
+                                 caches=pool, cache_index=start,
+                                 page_table=page_tables, slot_ids=slot_ids,
+                                 seq_lens=seq_lens, layer=li)
+        return (h, pool), None
 
-    x, new_caches = jax.lax.scan(unit_fn, x, (params["units"], cache))
+    (x, cache), _ = jax.lax.scan(
+        unit_fn, (x, cache),
+        (params["units"], jnp.arange(num_units(cfg), dtype=jnp.int32)))
     x = rms_final(params, cfg, x)
-    return head_logits(params, cfg, x), new_caches
+    return head_logits(params, cfg, x), cache
 
 
 def prefill(params: dict, cfg: ModelConfig, batch: dict, *,

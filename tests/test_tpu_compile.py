@@ -29,6 +29,7 @@ from repro.models import transformer as T
 
 INTERNVL2 = configs.get_config("internvl2-2b")
 HBM_BYTES = 16 * 2**30
+_PAGE_LEN = 128
 
 
 @pytest.fixture(scope="module")
@@ -98,13 +99,14 @@ def test_interpret_default_follows_backend(monkeypatch):
     assert kernels.resolve_interpret(True) is True
 
 
-def _paged_step(step, one_chip):
-    """internvl2-2b at its published widths (2 of its 24 layers, for test
-    time) in the rehearsed pool geometry: 8 slots of 4096 tokens, pages
-    of 128, 257 pages.  The step is jitted as PagedServeEngine jits it,
-    with the pool donated; returns it compiled."""
-    cfg = dataclasses.replace(INTERNVL2, num_layers=2)
-    slots, pages_per_seq, page_len, num_pages = 8, 32, 128, 257
+def _paged_step(step, one_chip, *, layers=2, slots=8, num_pages=257):
+    """internvl2-2b at its published widths (by default 2 of its 24
+    layers, for test time) in the rehearsed pool geometry: 8 slots of
+    4096 tokens, pages of 128, 257 pages.  The step is jitted as
+    PagedServeEngine jits it, with the pool donated; returns it
+    compiled."""
+    cfg = dataclasses.replace(INTERNVL2, num_layers=layers)
+    pages_per_seq, page_len = 4096 // _PAGE_LEN, _PAGE_LEN
 
     def on_chip(tree):
         return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
@@ -112,8 +114,7 @@ def _paged_step(step, one_chip):
 
     params = on_chip(jax.eval_shape(functools.partial(T.init_params, cfg),
                                     jax.random.key(0)))
-    cache = on_chip(jax.eval_shape(lambda: T.init_paged_cache(
-        cfg, num_pages, page_len, slots)))
+    cache = on_chip(_pool(cfg, num_pages, slots))
     batch, s = (slots, 1) if step == "decode" else (1, page_len)
 
     def i32(*shape):
@@ -128,12 +129,59 @@ def _paged_step(step, one_chip):
         i32(batch), seq_lens).compile()
 
 
+def _pool(cfg, num_pages, slots):
+    """Shapes and dtypes of the paged pool."""
+    return jax.eval_shape(lambda: T.init_paged_cache(cfg, num_pages,
+                                                     _PAGE_LEN, slots))
+
+
+def _used_bytes(compiled) -> int:
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 0, "the pool is not donated"
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
 def test_paged_step_fits_one_chip(step, one_chip):
-    mem = _paged_step(step, one_chip).memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    assert mem.alias_size_in_bytes > 0, "the pool is not donated"
+    used = _used_bytes(_paged_step(step, one_chip))
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
+
+
+#: an HLO instruction's result shape and opcode, for the ops that move a
+#: whole buffer: ``%copy.81 = bf16[2,257,128,8,128]{...} copy(``
+_MOVE_OP = re.compile(r"= [a-z0-9]+\[([\d,]*)\]\S* "
+                      r"(copy|copy-done|dynamic-slice|dynamic-update-slice)\(")
+
+
+def _squeeze(shape) -> tuple:
+    return tuple(d for d in shape if d != 1)
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_paged_step_updates_pool_in_place(step, one_chip):
+    """The pool rides the layer scan's carry: no temp buffer of a pool
+    leaf's size, and no copy, slice or slice update of a whole pool leaf,
+    stacked or one layer's.  Only the scatter touches the pool."""
+    compiled = _paged_step(step, one_chip)
+    leaf = jax.tree.leaves(_pool(dataclasses.replace(INTERNVL2,
+                                                     num_layers=2), 257, 8))[0]
+    leaf_bytes = leaf.size * leaf.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < leaf_bytes / 16, f"temp {temp / 2**30:.3f} GiB"
+    pool_shapes = {_squeeze(leaf.shape), _squeeze(leaf.shape[1:])}
+    moves = [m.group(0) for m in _MOVE_OP.finditer(compiled.as_text())
+             if _squeeze(int(d) for d in m.group(1).split(",") if d)
+             in pool_shapes]
+    assert not moves, moves
+
+
+def test_sixteen_slot_decode_step_fits_one_chip(one_chip):
+    """All 24 layers of internvl2-2b decoding 16 slots of 4096 tokens
+    over a pool of 513 pages (twice the 8-slot pool) compile for one chip
+    and fit its memory."""
+    used = _used_bytes(_paged_step("decode", one_chip, layers=24, slots=16,
+                                   num_pages=513))
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
 
 
