@@ -1,5 +1,7 @@
 """granite-8b [dense] — 36L d_model=4096 32H (GQA kv=8) d_ff=14336
-vocab=49152 — llama-arch, code [arXiv:2405.04324; hf]."""
+vocab=49152 — llama-arch, code, with attention and MLP biases and a head
+tied to the embedding (ibm-granite/granite-8b-code-base config.json)
+[arXiv:2405.04324; hf]."""
 
 import dataclasses
 
@@ -15,6 +17,11 @@ CONFIG = ModelConfig(
     num_heads=32,
     num_kv_heads=8,
     head_dim=128,
+    rope_theta=1e7,
+    norm_eps=1e-5,
+    attention_bias=True,
+    mlp_bias=True,
+    tie_embeddings=True,
 )
 
 

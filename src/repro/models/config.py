@@ -29,6 +29,8 @@ class ModelConfig:
     head_dim: int = 0
     rope_theta: float = 1e4
     causal: bool = True
+    attention_bias: bool = False    # biases on q/k/v/o (GQA path only)
+    mlp_bias: bool = False          # biases on gate/up/down (dense FFN)
 
     # -- MLA (deepseek-v2) --
     use_mla: bool = False

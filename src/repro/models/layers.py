@@ -40,6 +40,10 @@ PARAM_AXES: dict[str, tuple[str | None, ...]] = {
     "wk":           ("embed", "kv_features"),
     "wv":           ("embed", "kv_features"),
     "wo":           ("q_features", "embed"),
+    "bq":           ("q_features",),
+    "bk":           ("kv_features",),
+    "bv":           ("kv_features",),
+    "bo":           ("embed",),
     # MLA
     "w_dq":         ("embed", None),
     "w_dkv":        ("embed", "kv_lora"),
@@ -51,6 +55,9 @@ PARAM_AXES: dict[str, tuple[str | None, ...]] = {
     "w_gate":       ("embed", "mlp"),
     "w_up":         ("embed", "mlp"),
     "w_down":       ("mlp", "embed"),
+    "b_gate":       ("mlp",),
+    "b_up":         ("mlp",),
+    "b_down":       ("embed",),
     # MoE
     "router":       ("embed", "experts"),
     "moe_gate":     ("experts", "embed", "mlp"),
@@ -232,13 +239,44 @@ def init_attention(key, cfg: ModelConfig) -> dict:
     ks = jax.random.split(key, 5)
     d, hq, hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     pd = cfg.parameter_dtype
-    return {
+    out = {
         "attn_norm": jnp.ones((d,), pd),
         "wq": _init(ks[0], (d, hq * hd), d, pd),
         "wk": _init(ks[1], (d, hkv * hd), d, pd),
         "wv": _init(ks[2], (d, hkv * hd), d, pd),
         "wo": _init(ks[3], (hq * hd, d), hq * hd, pd),
     }
+    if cfg.attention_bias:
+        kb = jax.random.split(ks[4], 4)
+        out.update(bq=_init(kb[0], (hq * hd,), d, pd),
+                   bk=_init(kb[1], (hkv * hd,), d, pd),
+                   bv=_init(kb[2], (hkv * hd,), d, pd),
+                   bo=_init(kb[3], (d,), hq * hd, pd))
+    return out
+
+
+@scoped("attn_proj")
+def _qkv_proj(p: dict, xn: jax.Array, cfg: ModelConfig):
+    """q (B,S,H,D), k and v (B,S,Hkv,D): projections of the normed input,
+    each with its bias under ``attention_bias`` (added before rotary, as
+    llama does)."""
+    b, s, _ = xn.shape
+
+    def proj(w, bias, heads):
+        y = xn @ p[w]
+        if cfg.attention_bias:
+            y = y + p[bias]
+        return y.reshape(b, s, heads, cfg.head_dim)
+
+    return (proj("wq", "bq", cfg.num_heads),
+            proj("wk", "bk", cfg.num_kv_heads),
+            proj("wv", "bv", cfg.num_kv_heads))
+
+
+@scoped("attn_proj")
+def _out_proj(p: dict, o: jax.Array, cfg: ModelConfig) -> jax.Array:
+    y = o @ p["wo"]
+    return y + p["bo"] if cfg.attention_bias else y
 
 
 @scoped("attention")
@@ -320,11 +358,9 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
                     layer: jax.Array | None = None
                     ) -> tuple[jax.Array, dict | None]:
     b, s, d = x.shape
-    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    hq, hd = cfg.num_heads, cfg.head_dim
     xn = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q = (xn @ p["wq"]).reshape(b, s, hq, hd)
-    k = (xn @ p["wk"]).reshape(b, s, hkv, hd)
-    v = (xn @ p["wv"]).reshape(b, s, hkv, hd)
+    q, k, v = _qkv_proj(p, xn, cfg)
     q = rotary(q, positions, cfg.rope_theta)
     k = rotary(k, positions, cfg.rope_theta)
     q = constrain(q, "batch", "seq", "heads", "head_dim")
@@ -388,7 +424,7 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
         new_cache = {"k": ck, "v": cv}
     o = o.reshape(b, s, hq * hd)
     o = constrain(o, "batch", "seq", "q_features")
-    return x + (o @ p["wo"]).astype(x.dtype), new_cache
+    return x + _out_proj(p, o, cfg).astype(x.dtype), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -517,16 +553,26 @@ def init_ffn(key, cfg: ModelConfig, d_ff: int | None = None,
     }
     if not prefix:
         out["ffn_norm"] = jnp.ones((d,), pd)
+        if cfg.mlp_bias:
+            kb = jax.random.split(jax.random.fold_in(key, 1), 3)
+            out.update(b_gate=_init(kb[0], (f,), d, pd),
+                       b_up=_init(kb[1], (f,), d, pd),
+                       b_down=_init(kb[2], (d,), f, pd))
     return out
 
 
 def apply_ffn(p: dict, x: jax.Array, cfg: ModelConfig,
               prefix: str = "") -> jax.Array:
     n = lambda s: (prefix + s) if prefix else s
-    h = jax.nn.silu(x @ p[n("w_gate")]) * (x @ p[n("w_up")])
+    bias = cfg.mlp_bias and not prefix      # shared experts have none
+    gate = x @ p[n("w_gate")]
+    gate = jax.nn.silu(gate + p["b_gate"] if bias else gate)
+    up = x @ p[n("w_up")]
+    h = gate * (up + p["b_up"] if bias else up)
     h = (constrain(h, "batch", "seq", "mlp") if h.ndim == 3
          else constrain(h, "batch", "mlp"))   # shared-expert path: (T, d)
-    return (h @ p[n("w_down")]).astype(x.dtype)
+    y = h @ p[n("w_down")]
+    return (y + p["b_down"] if bias else y).astype(x.dtype)
 
 
 @scoped("mlp")

@@ -28,6 +28,7 @@ from repro.kernels import dbuf_copy, flash_attention, memcpy, pchase, \
 from repro.models import transformer as T
 
 INTERNVL2 = configs.get_config("internvl2-2b")
+GRANITE = configs.get_config("granite-8b")
 HBM_BYTES = 16 * 2**30
 _PAGE_LEN = 128
 
@@ -99,13 +100,14 @@ def test_interpret_default_follows_backend(monkeypatch):
     assert kernels.resolve_interpret(True) is True
 
 
-def _paged_step(step, one_chip, *, layers=2, slots=8, num_pages=257):
-    """internvl2-2b at its published widths (by default 2 of its 24
-    layers, for test time) in the rehearsed pool geometry: 8 slots of
+def _paged_step(step, one_chip, *, model=INTERNVL2, layers=2, slots=8,
+                num_pages=257):
+    """``model`` (internvl2-2b) at its published widths (by default 2 of
+    its layers, for test time) in the rehearsed pool geometry: 8 slots of
     4096 tokens, pages of 128, 257 pages.  The step is jitted as
     PagedServeEngine jits it, with the pool donated; returns it
     compiled."""
-    cfg = dataclasses.replace(INTERNVL2, num_layers=layers)
+    cfg = dataclasses.replace(model, num_layers=layers)
     pages_per_seq, page_len = 4096 // _PAGE_LEN, _PAGE_LEN
 
     def on_chip(tree):
@@ -206,7 +208,107 @@ def test_named_scopes_change_op_metadata_only(step, one_chip, monkeypatch):
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = _paged_step(step, one_chip).as_text()
-    for scope in ("kv_write", "kv_gather", "attention", "mlp", "head"):
+    for scope in ("kv_write", "kv_gather", "attn_proj", "attention", "mlp",
+                  "head"):
         assert f"/{scope}/" in scoped, scope
         assert f"/{scope}/" not in plain, scope
     assert _strip_metadata(scoped) == _strip_metadata(plain)
+
+
+def _reserved_gib(compiled) -> float:
+    """Arguments + temp of a compiled step, as the benchmark's
+    ``step_hbm_gib`` reads them."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30
+
+
+def test_internvl2_decode_step_reservation_is_unchanged(one_chip):
+    """All 24 layers of internvl2-2b, which has no biases: the decode step
+    reserves what it did before the bias flags existed."""
+    compiled = _paged_step("decode", one_chip, layers=24)
+    assert round(_reserved_gib(compiled), 3) == 6.531
+
+
+#: the benchmark cell's cut of granite-8b: 18 of its 36 layers
+GRANITE_LAYERS = 18
+
+
+@pytest.fixture(scope="module")
+def granite_steps(one_chip):
+    """The granite-8b cell's decode and chunk steps, compiled once."""
+    return {step: _paged_step(step, one_chip, model=GRANITE,
+                              layers=GRANITE_LAYERS)
+            for step in ("decode", "prefill_chunk")}
+
+
+def test_granite_decode_step_fits_one_chip(granite_steps):
+    """Biased, tied granite-8b at the cell's sizes: 4,128,120,832 bf16
+    parameters (7.690 GiB) and the 257-page pool (2.259 GiB) are the
+    arguments; the step needs under a MiB of temp beside them."""
+    compiled = granite_steps["decode"]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**20
+    assert round(_reserved_gib(compiled), 3) == 9.949
+    assert _used_bytes(compiled) < HBM_BYTES
+
+
+#: a result shape in the HLO text: ``%x = f32[49152,4096]{...} opcode(``
+_RESULT = re.compile(r"^\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([\d,]*)\]\S* "
+                     r"([a-z\-]+)\(")
+#: a computation's header line, and a fusion's body it names
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) ")
+_CALLS = re.compile(r" fusion\(.*calls=%([\w.\-]+)")
+#: results that name a buffer and copy nothing
+_VIEWS = {"parameter", "get-tuple-element", "bitcast"}
+
+
+def _materialized(hlo: str):
+    """(result shape, opcode, line) of every instruction that writes a
+    buffer of its own: those outside fusion bodies, other than views."""
+    fused = set(_CALLS.findall(hlo))
+    out, inside = [], None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head and line.rstrip().endswith("{"):
+            inside = head.group(1)
+            continue
+        m = _RESULT.match(line)
+        if m and inside not in fused and m.group(2) not in _VIEWS:
+            shape = tuple(int(d) for d in m.group(1).split(",") if d)
+            out.append((shape, m.group(2), line.strip()[:160]))
+    return out
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_tied_head_reads_the_table_in_place(step, granite_steps):
+    """No op of the step writes a buffer of the embedding table's shape,
+    transposed or not, in any dtype: the embedding lookup and the tied
+    head read the (49152, 4096) table where it lies."""
+    ops = _materialized(granite_steps[step].as_text())
+    assert ops, "no instruction parsed"
+    table = {(GRANITE.vocab_size, GRANITE.d_model),
+             (GRANITE.d_model, GRANITE.vocab_size)}
+    copies = [op for op in ops if op[0] in table]
+    assert not copies, copies
+
+
+def test_table_check_sees_a_copy(one_chip):
+    """The check above finds a transposed float32 copy of a table where
+    a step does make one."""
+    table = _spec((GRANITE.vocab_size, 512), jnp.bfloat16, one_chip)
+    x = _spec((8, 512), jnp.float32, one_chip)
+
+    def copies(t, x):
+        tt = t.T.astype(jnp.float32)
+        return x @ tt, tt
+
+    hlo = jax.jit(copies).lower(table, x).compile().as_text()
+    assert any(op[0] == (512, GRANITE.vocab_size)
+               for op in _materialized(hlo))
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_granite_step_names_its_parts(step, granite_steps):
+    hlo = granite_steps[step].as_text()
+    for scope in ("attn_proj", "mlp", "head"):
+        assert f"/{scope}/" in hlo, scope
