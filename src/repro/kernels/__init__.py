@@ -1,5 +1,6 @@
 """Pallas TPU kernels: the paper's microbenchmarks (P-chase, streaming
-copy, strided access) and the model's hot spots (flash attention, RMSNorm).
+copy, strided access) and the model's hot spots (flash attention, paged
+decode attention, RMSNorm).
 
 Every kernel takes ``interpret``; ``None`` (the default everywhere) means
 compile for the chip when the default backend is a TPU and run the kernel

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import memcpy as _mc
+from repro.kernels import paged_attention as _pa
 from repro.kernels import pchase as _pc
 from repro.kernels import ref
 from repro.kernels import strided as _st
@@ -88,6 +89,15 @@ def flash_attention(q, k, v, *, num_q_heads: int, num_kv_heads: int,
         q, k, v, num_q_heads=num_q_heads, num_kv_heads=num_kv_heads,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret)
+
+
+def paged_decode_attention(q, k_pages, v_pages, layer, page_table, lengths,
+                           *, interpret: bool | None = None):
+    """One query token per row over the pages its length covers, read
+    from the stacked paged pool (see ``kernels.paged_attention``)."""
+    return _pa.paged_decode_attention(q, k_pages, v_pages, layer,
+                                      page_table, lengths,
+                                      interpret=interpret)
 
 
 def attention(q, k, v, *, num_q_heads: int, num_kv_heads: int,

@@ -235,6 +235,31 @@ def _paged_valid(t: int, positions: jax.Array) -> jax.Array:
     return jnp.arange(t)[None, None, :] <= positions[:, :, None]
 
 
+def _paged_decode_kernel_applies(q: jax.Array) -> bool:
+    """Whether paged attention for ``q`` (B, S, H, D) runs as the paged
+    decode kernel: one query token per row, no sharding context (the
+    kernel reads whole pages of one device's pool; under a serving mesh
+    the pool is sharded), lane-wide heads (D a multiple of 128), and a TPU
+    to compile it for.  Everything else (prefill chunks, serving meshes,
+    narrow test models, the CPU) gathers the table and runs
+    :func:`_sdpa`."""
+    return (q.shape[1] == 1 and sharding.current() is None
+            and q.shape[-1] % 128 == 0 and jax.default_backend() == "tpu")
+
+
+@scoped("attention")
+def _paged_decode_attention(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, layer: jax.Array,
+                            page_table: jax.Array,
+                            positions: jax.Array) -> jax.Array:
+    """q: (B, 1, H, D) at ``positions`` (B, 1), whose K/V are already in
+    the pool: each row attends to its first ``position + 1`` tokens, read
+    page by page from the stacked pool (``kernels.paged_attention``)."""
+    o = kops.paged_decode_attention(q[:, 0], k_pages, v_pages, layer,
+                                    page_table, positions[:, 0] + 1)
+    return o[:, None]
+
+
 def init_attention(key, cfg: ModelConfig) -> dict:
     ks = jax.random.split(key, 5)
     d, hq, hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -373,15 +398,21 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, *,
         new_cache = {"k": k, "v": v}
     elif page_table is not None:
         # paged cache: scatter this step's K/V into this layer's rows of
-        # the stacked page pool, gather each slot's pages back, mask by
-        # absolute position.  Covers both one-token decode (s=1) and
-        # chunked prefill (s=chunk).
+        # the stacked page pool, then attend over each slot's pages: a
+        # one-token decode reads only its live pages in the paged kernel
+        # where that applies; otherwise (chunked prefill, s=chunk, among
+        # others) every slot's pages are gathered back and masked by
+        # absolute position.
         ck = _paged_scatter(cache["k"], layer, page_table, positions, k)
         cv = _paged_scatter(cache["v"], layer, page_table, positions, v)
-        kg = _paged_gather(ck, layer, page_table)
-        vg = _paged_gather(cv, layer, page_table)
-        o = _sdpa(q, kg, vg, cfg, causal=False,
-                  kv_len_mask=_paged_valid(kg.shape[1], positions))
+        if _paged_decode_kernel_applies(q):
+            o = _paged_decode_attention(q, ck, cv, layer, page_table,
+                                        positions)
+        else:
+            kg = _paged_gather(ck, layer, page_table)
+            vg = _paged_gather(cv, layer, page_table)
+            o = _sdpa(q, kg, vg, cfg, causal=False,
+                      kv_len_mask=_paged_valid(kg.shape[1], positions))
         new_cache = {"k": ck, "v": cv}
     elif cache_index is not None and jnp.ndim(cache_index) == 1:
         # continuous batching: per-slot cache positions (B,)

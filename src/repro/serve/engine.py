@@ -279,6 +279,10 @@ class PagedServeEngine:
         self.max_slack_tokens = 0
         self.exports = 0               # KV handoffs out (tiered fleet)
         self.imports = 0               # KV handoffs in
+        # decode attention's pages: those the decoding rows' lengths cover
+        # (what the paged kernel reads) and those the page tables span
+        self.kv_pages_read = 0
+        self.kv_pages_tabled = 0
         self._admit_counter = 0
 
         # the ctx must be ACTIVE at trace time (layers' paged scatter /
@@ -504,17 +508,21 @@ class PagedServeEngine:
                 return None
             # batch rows without a DECODING request (free slots, but also
             # slots still mid-prefill) are retargeted at the scratch page /
-            # scratch slot row so their garbage writes cannot corrupt live
-            # state
+            # scratch slot row at position 0 so their garbage writes cannot
+            # corrupt live state, and their attention reads one page
             mask = np.zeros(self.max_slots, dtype=bool)
             mask[list(self.active)] = True
             tables = np.where(mask[:, None], self.page_tables, 0)
             slot_ids = np.where(mask, np.arange(self.max_slots),
                                 self.max_slots)
+            positions = np.where(mask, self.positions, 0)
+            self.kv_pages_read += int(
+                (positions[mask] // self.page_len + 1).sum())
+            self.kv_pages_tabled += tables.size
             toks = jnp.asarray(self.last_tokens[:, None], jnp.int32)
             logits, self.cache = self._decode_step(
                 self.params, self.cache, toks,
-                jnp.asarray(self.positions, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
                 jnp.asarray(tables, jnp.int32),
                 jnp.asarray(slot_ids, jnp.int32))
         with TraceAnnotation(spans.SYNC):
@@ -778,5 +786,7 @@ class PagedServeEngine:
                 "num_pages": self.alloc.num_pages,
                 "peak_pages": self.peak_pages,
                 "max_slack_tokens": self.max_slack_tokens,
+                "kv_pages_read": self.kv_pages_read,
+                "kv_pages_tabled": self.kv_pages_tabled,
                 "avg_batch_occupancy":
                     self.decoded_tokens / max(1, self.steps) / self.max_slots}

@@ -7,8 +7,10 @@ on the profiler's host plane, whose clock the device's op events share;
 with no profiler running it costs about a microsecond and records nothing.
 
 The jitted steps name their parts with ``jax.named_scope`` instead
-(``kv_write``, ``kv_gather``, ``attention``, ``mlp``, ``head``): those
-reach the compiled HLO's ``op_name`` metadata, not the host plane.
+(``kv_write``, ``kv_gather``, ``attention``, ``mlp``, ``head``; on a TPU
+decode attention is one paged kernel under ``attention``, with no
+``kv_gather``): those reach the compiled HLO's ``op_name`` metadata, not
+the host plane.
 """
 
 #: the front end taking one request (stat ``uid``)

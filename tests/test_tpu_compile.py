@@ -16,6 +16,7 @@ import dataclasses
 import functools
 import os
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -23,8 +24,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs, kernels
-from repro.kernels import dbuf_copy, flash_attention, memcpy, pchase, \
-    rmsnorm, strided
+from repro.kernels import dbuf_copy, flash_attention, memcpy, \
+    paged_attention, pchase, rmsnorm, strided
 from repro.models import transformer as T
 
 INTERNVL2 = configs.get_config("internvl2-2b")
@@ -76,6 +77,14 @@ KERNELS = {
         [((_HEADS, 2048, _HEAD_DIM), jnp.bfloat16),
          ((_KV_HEADS, 2048, _HEAD_DIM), jnp.bfloat16),
          ((_KV_HEADS, 2048, _HEAD_DIM), jnp.bfloat16)]),
+    # the decode step's paged attention at internvl2-2b's geometry: 8 rows
+    # of 32 pages over the 24 layers' stacked 257-page pool
+    "paged_decode_attention": (
+        paged_attention.paged_decode_attention,
+        [((8, _HEADS, _HEAD_DIM), jnp.bfloat16),
+         ((24, 257, _PAGE_LEN, _KV_HEADS, _HEAD_DIM), jnp.bfloat16),
+         ((24, 257, _PAGE_LEN, _KV_HEADS, _HEAD_DIM), jnp.bfloat16),
+         ((), jnp.int32), ((8, 32), jnp.int32), ((8,), jnp.int32)]),
     "pchase": (functools.partial(pchase.pchase_trace, iterations=4096),
                [((1 << 20,), jnp.int32)]),
     "strided": (functools.partial(strided.strided_gather, stride=6),
@@ -105,8 +114,9 @@ def _paged_step(step, one_chip, *, model=INTERNVL2, layers=2, slots=8,
     """``model`` (internvl2-2b) at its published widths (by default 2 of
     its layers, for test time) in the rehearsed pool geometry: 8 slots of
     4096 tokens, pages of 128, 257 pages.  The step is jitted as
-    PagedServeEngine jits it, with the pool donated; returns it
-    compiled."""
+    PagedServeEngine jits it, with the pool donated, and traced as on the
+    chip (the default backend reads as a TPU, so decode attention takes
+    the paged kernel); returns it compiled."""
     cfg = dataclasses.replace(model, num_layers=layers)
     pages_per_seq, page_len = 4096 // _PAGE_LEN, _PAGE_LEN
 
@@ -126,9 +136,10 @@ def _paged_step(step, one_chip, *, model=INTERNVL2, layers=2, slots=8,
         return T.paged_step(p, cfg, c, t, st, tab, sl, sq)
 
     seq_lens = None if step == "decode" else i32(batch)
-    return jax.jit(step_fn, donate_argnums=1).lower(
-        params, cache, i32(batch, s), i32(batch), i32(batch, pages_per_seq),
-        i32(batch), seq_lens).compile()
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return jax.jit(step_fn, donate_argnums=1).lower(
+            params, cache, i32(batch, s), i32(batch),
+            i32(batch, pages_per_seq), i32(batch), seq_lens).compile()
 
 
 def _pool(cfg, num_pages, slots):
@@ -194,10 +205,23 @@ _DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations",
 
 def _strip_metadata(hlo: str) -> str:
     """The HLO text without op metadata and the source tables it points
-    into: what is left is the program."""
+    into, its instructions and computations renamed in order of first
+    appearance (the lowering numbers names by what it met before, scope
+    names included): what is left is the program."""
     lines = [ln for ln in hlo.splitlines()
              if ln not in _DEBUG_TABLES and not re.match(r"\d+ ", ln)]
-    return re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+    text = re.sub(r", metadata=\{[^}]*\}", "", "\n".join(lines))
+    names: dict = {}
+    return re.sub(r"%([\w.\-]+)",
+                  lambda m: "%" + names.setdefault(m.group(1),
+                                                   f"n{len(names)}"), text)
+
+
+#: the scopes of each step: decode attention reads the pool in the paged
+#: kernel (scope ``attention``), the chunk gathers its pages first
+_SCOPES = {"decode": ("kv_write", "attn_proj", "attention", "mlp", "head"),
+           "prefill_chunk": ("kv_write", "kv_gather", "attn_proj",
+                             "attention", "mlp", "head")}
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
@@ -208,10 +232,11 @@ def test_named_scopes_change_op_metadata_only(step, one_chip, monkeypatch):
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = _paged_step(step, one_chip).as_text()
-    for scope in ("kv_write", "kv_gather", "attn_proj", "attention", "mlp",
-                  "head"):
+    for scope in _SCOPES[step]:
         assert f"/{scope}/" in scoped, scope
         assert f"/{scope}/" not in plain, scope
+    if step == "decode":
+        assert "/kv_gather/" not in scoped
     assert _strip_metadata(scoped) == _strip_metadata(plain)
 
 
@@ -222,11 +247,16 @@ def _reserved_gib(compiled) -> float:
     return (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30
 
 
-def test_internvl2_decode_step_reservation_is_unchanged(one_chip):
+@pytest.fixture(scope="module")
+def internvl2_decode(one_chip):
+    """All 24 layers of internvl2-2b's decode step, compiled once."""
+    return _paged_step("decode", one_chip, layers=24)
+
+
+def test_internvl2_decode_step_reservation_is_unchanged(internvl2_decode):
     """All 24 layers of internvl2-2b, which has no biases: the decode step
     reserves what it did before the bias flags existed."""
-    compiled = _paged_step("decode", one_chip, layers=24)
-    assert round(_reserved_gib(compiled), 3) == 6.531
+    assert round(_reserved_gib(internvl2_decode), 3) == 6.531
 
 
 #: the benchmark cell's cut of granite-8b: 18 of its 36 layers
@@ -312,3 +342,42 @@ def test_granite_step_names_its_parts(step, granite_steps):
     hlo = granite_steps[step].as_text()
     for scope in ("attn_proj", "mlp", "head"):
         assert f"/{scope}/" in hlo, scope
+
+
+def _result_shapes(hlo: str) -> set:
+    """The result shape of every instruction, inside fusions too, without
+    its unit dimensions."""
+    return {_squeeze(int(d) for d in m.group(1).split(",") if d)
+            for m in map(_RESULT.match, hlo.splitlines()) if m}
+
+
+def _gathered(model, batch: int) -> set:
+    """A gathered table of ``batch`` rows of 32 pages, flat or paged,
+    without unit dimensions."""
+    return {_squeeze((batch, 32 * _PAGE_LEN, model.num_kv_heads,
+                      model.head_dim)),
+            _squeeze((batch, 32, _PAGE_LEN, model.num_kv_heads,
+                      model.head_dim))}
+
+
+@pytest.mark.parametrize("model", ["internvl2-2b", "granite-8b"])
+def test_decode_step_attends_in_the_paged_kernel(model, internvl2_decode,
+                                                 granite_steps):
+    """Both benchmark models' decode steps (24 and 18 layers) run the
+    paged kernel, compiled by Mosaic, once in the layer scan's body; no op
+    of the step holds a gathered table of every row's pages."""
+    cfg, hlo = ((INTERNVL2, internvl2_decode.as_text())
+                if model == "internvl2-2b"
+                else (GRANITE, granite_steps["decode"].as_text()))
+    calls = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "paged_decode_attention" in calls[0], calls
+    assert not _result_shapes(hlo) & _gathered(cfg, 8)
+
+
+def test_chunk_step_still_gathers(granite_steps):
+    """The control: a prefill chunk gathers its row's whole table (and so
+    the check above sees a gathered table where a step makes one)."""
+    hlo = granite_steps["prefill_chunk"].as_text()
+    assert "tpu_custom_call" not in hlo
+    assert _result_shapes(hlo) & _gathered(GRANITE, 1)
