@@ -23,7 +23,7 @@ spectrum doing placement.  Every verdict is deterministic accounting:
 * **classification under faults**: killing a prefill and a decode
   replica mid-run still classifies every uid.
 
-Handoff counts/aborts ride along as info metrics.
+Handoff counts ride along as info metrics.
 """
 
 from __future__ import annotations
@@ -179,11 +179,8 @@ def run(ctx: Context) -> list[Metric]:
                detail=f"outcomes: "
                       f"{sorted(set(cls.values()))}"),
         info("tiered_handoffs", st_tier["handoffs"]),
-        info("tiered_handoff_aborts", st_tier["handoff_aborts"]),
         info("fault_run_handoffs", st_fault["handoffs"]),
         info("symmetric_ticks", st_sym["ticks"]),
-        info("tiered_ticks", st_tier["ticks"],
-             detail="handoff ticks land in TTFT, so a tiered fleet "
-                    "trades latency for specialization"),
+        info("tiered_ticks", st_tier["ticks"]),
     ]
     return metrics

@@ -224,10 +224,9 @@ def serving_doc() -> str:
         "prompt, its WHOLE pages move: `handoff_bytes = pages × "
         "page_len × kv_bytes_per_token`, priced at `min(src, dst)` "
         "measured global-memory bandwidth plus one worst-endpoint DRAM "
-        "round trip (`handoff_seconds`), then quantized against the "
-        "destination's decode step (`handoff_ticks`, never 0) — the "
-        "first sampled token is withheld in transit, so handoff "
-        "latency lands in TTFT, never vanishes between tiers;",
+        "round trip (`handoff_seconds`). The pages move in the fleet "
+        "tick that routes them, so the first sampled token streams the "
+        "tick its prefill completes;",
         "3. **stage 2 (handoff placement)** — decode-tier candidates "
         "with import capacity, priced with `decode_cell_cost` at live "
         "load PLUS the per-candidate transfer term, under the same "
@@ -257,10 +256,10 @@ def serving_doc() -> str:
         "`export_pages`/`import_pages` move the cache token-major, so "
         "tiers may disagree about `page_len`; allocator invariants run "
         "on both ends and no stream is ever resident in two tiers' "
-        "page tables at once. Killing a replica mid-handoff aborts the "
-        "transfer deterministically: the request re-enters the prefill "
-        "tier and classifies `requeued`/`migrated`, never lost "
-        "silently. `planner.plan_tiers` answers the sizing question "
+        "page tables at once. Killing a decode replica rolls its "
+        "handed-off requests back through the prefill tier, so they "
+        "classify `requeued`/`migrated`, never lost silently. "
+        "`planner.plan_tiers` answers the sizing question "
         "per tier — how many prefill vs decode replicas of which "
         "profile — with the handoff folded into predicted TTFT.",
         "",
@@ -626,7 +625,7 @@ def cli_doc() -> str:
     # imports are local: the launchers pull jax (and set XLA_FLAGS), which
     # registry discovery must not pay for
     from repro.bench import __main__ as bench_main
-    from repro.launch import dryrun, perf, serve, train
+    from repro.launch import dryrun, serve, train
 
     lines = [
         "# CLI reference",
@@ -643,9 +642,6 @@ def cli_doc() -> str:
                             bench_main.build_parser())
     lines += _render_parser("Serving launcher", "python -m repro.launch.serve",
                             serve.build_parser())
-    lines += _render_parser("Perf hillclimbing driver",
-                            "python -m repro.launch.perf",
-                            perf.build_parser())
     lines += _render_parser("Training launcher",
                             "python -m repro.launch.train",
                             train.build_parser())

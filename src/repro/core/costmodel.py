@@ -6,8 +6,7 @@ under-reports by the trip count (verified empirically — see
 tests/test_costmodel.py, which also validates this model against XLA on
 scan-free unrolled configs).  The dry-run keeps the compiled artifact for
 memory/sharding/collective-schedule evidence; the roofline *terms* come
-from here.  This module is also the napkin-math engine for §Perf: every
-hillclimb hypothesis is priced against it first.
+from here.
 
 Conventions: dot = 2mnk FLOPs; causal attention halves score/PV work;
 MoE compute follows the capacity actually dispatched (T·k·cf tokens).
@@ -264,9 +263,6 @@ def train_cell_cost(cfg: ModelConfig, *, global_batch: int, seq: int,
     fwd = forward_flops_per_token(cfg, kv_len=seq) * tokens
     if not plan.remat:
         mult = 3.0                         # fwd + 2×bwd
-    elif cfg.remat_policy == "dots":
-        mult = 3.35                        # matmul outputs saved: only the
-                                           # cheap elementwise work recomputes
     else:
         mult = 4.0                         # full remat: +1 forward recompute
     gflops = fwd * mult

@@ -6,9 +6,9 @@ with Mosaic; on the CPU, where the tests run (``JAX_PLATFORMS=cpu``), the
 kernel bodies execute in the Pallas interpreter exactly as written.
 ``tests/test_tpu_compile.py`` compiles the kernels for a described v5e
 chip, so a kernel Mosaic refuses fails there and not only on the chip.
-Model code calls these through ``attention()`` which picks the flash kernel
-or the jnp reference per config (`attention_impl`), so the dry-run can
-lower pure-XLA attention while kernel correctness is pinned by tests.
+Model code calls ``paged_decode_attention`` on a TPU's one-token paged
+decode step; the flash kernel is reached through ``attention(impl="flash")``
+and its correctness is pinned by tests.
 """
 
 from __future__ import annotations

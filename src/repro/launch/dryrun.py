@@ -205,8 +205,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str,
     if shape.kind != "train":
         model_flops /= 3.0                  # forward only: 2·N·D
 
-    # spec=None resolves through repro.core.profile: a launcher-installed
-    # dissected profile (perf.py --profile) reaches the roofline terms here
+    # spec=None resolves through repro.core.profile: an installed dissected
+    # profile reaches the roofline terms here
     report = roofline.analyze(
         f"{arch}__{shape_name}__{mesh_name}", cost=cost, hlo_text=hlo,
         chips=chips, spec=None, model_flops=model_flops,

@@ -272,9 +272,7 @@ def _fleet_run(cfg, params, args):
           f"{s['migrations']} migrations, {s['preemptions']} preemptions, "
           f"margin violations={len(fleet.margin_violations())}")
     if fleet.tiered:
-        print(f"handoffs: {s['handoffs']} completed, "
-              f"{s['handoff_aborts']} aborted, "
-              f"{s['in_transit']} in transit at drain")
+        print(f"handoffs: {s['handoffs']} completed")
     print(f"pages: peak={s['peak_pages']} leaked={s['pages_leaked']} "
           f"max slack={s['max_slack_tokens']} tok")
     for p in s["per_replica"]:
@@ -381,8 +379,7 @@ def _workload_run(cfg, params, args):
           f"migrations, {s['preemptions']} preemptions; pages: "
           f"peak={s['peak_pages']} leaked={s['pages_leaked']}")
     if front.fleet.tiered:
-        print(f"tiers: {s['tiers']} -> {s['handoffs']} handoffs, "
-              f"{s['handoff_aborts']} aborted")
+        print(f"tiers: {s['tiers']} -> {s['handoffs']} handoffs")
     plan = plan_for_trace(
         cfg, trace, spec=resolve_fleet_profile(profiles[0] if profiles
                                                else args.profile),

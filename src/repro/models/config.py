@@ -73,8 +73,7 @@ class ModelConfig:
     kv_cache_dtype: str = "bfloat16"   # "int8": quantized KV cache (decode)
     norm_eps: float = 1e-6
     remat: bool = True
-    remat_policy: str = "full"      # full | dots (save matmul outputs)
-    attention_impl: str = "ref"     # "ref" | "chunked" (XLA) | "flash" (Pallas)
+    attention_impl: str = "ref"     # "ref" | "chunked" (XLA)
     attention_chunk: int = 1024     # q-block for the chunked impl
     scan_layers: bool = True
     tie_embeddings: bool = False

@@ -312,13 +312,6 @@ def _sdpa(q, k, v, cfg: ModelConfig, *, causal: bool,
     mask (paged chunked prefill)."""
     b, s, h, dh = q.shape
     t, hkv = k.shape[1], k.shape[2]
-    if cfg.attention_impl == "flash" and kv_len_mask is None and s == t:
-        qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
-        kf = k.transpose(0, 2, 1, 3).reshape(b * hkv, t, dh)
-        vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, t, dh)
-        o = kops.flash_attention(qf, kf, vf, num_q_heads=h, num_kv_heads=hkv,
-                                 causal=causal)
-        return o.reshape(b, h, s, dh).transpose(0, 2, 1, 3)
     if (cfg.attention_impl == "chunked" and s > cfg.attention_chunk
             and s % cfg.attention_chunk == 0
             and (kv_len_mask is None or kv_len_mask.ndim == 2)):

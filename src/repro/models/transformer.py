@@ -206,12 +206,7 @@ def forward(params: dict, cfg: ModelConfig, batch: dict
                                  caches=None, cache_index=None)
         return (h, aux + aux2), None
 
-    if cfg.remat:
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat_policy == "dots" else None)
-        fn = jax.checkpoint(unit_fn, policy=policy)
-    else:
-        fn = unit_fn
+    fn = jax.checkpoint(unit_fn) if cfg.remat else unit_fn
     if cfg.scan_layers:
         (x, aux), _ = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)),
                                    params["units"])

@@ -3,7 +3,7 @@
 Model code never names mesh axes; it annotates activations/params with
 *logical* axes ("batch", "heads", "mlp", "experts", ...) and the active
 rule set resolves them onto the ("pod", "data", "model") mesh.  Rules are
-swappable per experiment — that is the knob the §Perf hillclimb turns.
+swappable per experiment.
 
 Robustness detail: a logical rule is silently dropped for a given tensor
 dimension when the dimension size is not divisible by the mesh-axis size

@@ -47,7 +47,7 @@ def install_profile(device_or_path: str, *,
                     require_kind: str = "tpu") -> DeviceProfile:
     """Launcher entry point: load, vet, and activate a profile.
 
-    One contract for every ``--profile`` flag (launch.serve, launch.perf):
+    One contract for every ``--profile`` flag (launch.serve):
     wrong-kind and stale artifacts fail *here*, at startup, with an
     actionable message — not minutes later inside a consumer.  Raises
     ``SystemExit``; returns the installed profile.

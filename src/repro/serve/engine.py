@@ -348,14 +348,20 @@ class PagedServeEngine:
         else:
             self.prefilling.remove(req)
 
+    def release(self, req: Request) -> None:
+        """Free a live request copy-free: its pages go to the free list,
+        its slot is freed, and it leaves whichever live structure holds
+        it."""
+        self.alloc.release(req.uid)
+        self.page_tables[req.slot][:] = 0
+        self.free_slots.append(req.slot)
+        self._drop_live(req)
+        req.slot = None
+
     def _preempt(self, victim: Request) -> None:
         """Copy-free rollback: pages to the free list, request re-queued
         for a full (deterministic, greedy) re-run."""
-        self.alloc.release(victim.uid)
-        self.page_tables[victim.slot][:] = 0
-        self.free_slots.append(victim.slot)
-        self._drop_live(victim)
-        victim.slot = None
+        self.release(victim)
         victim.generated = []
         victim.prefill_pos = 0
         self.waiting.appendleft(victim)
@@ -554,27 +560,16 @@ class PagedServeEngine:
 
     def cancel(self, uid: int) -> bool:
         """Abort a request wherever it is; frees its pages copy-free."""
-        for q in (self.waiting, self.prefilling, self.ready):
-            for r in q:
-                if r.uid == uid:
-                    q.remove(r)
-                    if r.slot is not None:
-                        self.alloc.release(uid)
-                        self.page_tables[r.slot][:] = 0
-                        self.free_slots.append(r.slot)
-                        r.slot = None
-                    self.cancelled.append(r)
-                    return True
-        for slot, r in list(self.active.items()):
-            if r.uid == uid:
-                del self.active[slot]
-                self.alloc.release(uid)
-                self.page_tables[slot][:] = 0
-                self.free_slots.append(slot)
-                r.slot = None
-                self.cancelled.append(r)
-                return True
-        return False
+        req = next((r for r in self.waiting if r.uid == uid), None)
+        if req is not None:
+            self.waiting.remove(req)
+        else:
+            req = next((r for r in self._live() if r.uid == uid), None)
+            if req is None:
+                return False
+            self.release(req)
+        self.cancelled.append(req)
+        return True
 
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:
         while (self.waiting or self.prefilling or self.ready or self.active) \
@@ -596,11 +591,7 @@ class PagedServeEngine:
         victims = sorted(self._live(), key=lambda r: r.admit_seq,
                          reverse=True)
         for req in victims:            # youngest first + appendleft ==
-            self.alloc.release(req.uid)  # oldest ends at the queue head
-            self.page_tables[req.slot][:] = 0
-            self.free_slots.append(req.slot)
-            self._drop_live(req)
-            req.slot = None
+            self.release(req)          # oldest ends at the queue head
             req.generated = []
             req.prefill_pos = 0
             self.waiting.appendleft(req)
@@ -664,26 +655,21 @@ class PagedServeEngine:
             "last_token": int(self.last_tokens[slot]),
             "leaves": jax.tree_util.tree_map_with_path(one, self.cache),
         }
-        self.alloc.release(uid)
-        self.page_tables[slot][:] = 0
-        self.free_slots.append(slot)
-        self.ready.remove(req)
-        req.slot = None
+        self.release(req)
         self.exports += 1
         self.alloc.check_invariants()
         return req, payload
 
-    def import_pages(self, req: Request, payload: dict) -> bool:
+    def import_pages(self, req: Request, payload: dict) -> None:
         """Land a handed-off request: allocate pages for its stored
         prefix, scatter the payload into this pool's geometry, and put
         it straight into decode.  Seniority is engine-local, so the
         arrival enters this engine's admission order at the back (the
-        same rule migration uses).  Returns False — leaving the engine
-        untouched — when capacity evaporated since the routing decision;
-        the fleet then rolls the request back instead."""
+        same rule migration uses).  The caller checked
+        :meth:`can_import`; an import that does not fit is a bug."""
         tokens = payload["tokens"]
-        if not self.can_import(tokens):
-            return False
+        assert self.can_import(tokens), \
+            f"uid {req.uid}: import of {tokens} tokens does not fit"
         slot = self.free_slots.popleft()
         req.slot = slot
         req.admit_seq = self._admit_counter
@@ -713,7 +699,6 @@ class PagedServeEngine:
         self.active[slot] = req
         self.imports += 1
         self.alloc.check_invariants()
-        return True
 
     def check_invariants(self) -> None:
         """Allocator invariants plus engine<->allocator cross-consistency

@@ -62,14 +62,13 @@ the request parks in the engine's ``ready`` queue instead of decoding.
 Stage 2 then routes a **KV handoff**: ``decode_cell_cost`` at the
 destination's load *plus* the paged-page transfer priced by
 ``min(src, dst)`` measured global-memory bandwidth
-(:func:`repro.serve.tiers.handoff_seconds`).  The handoff occupies
-:func:`~repro.serve.tiers.handoff_ticks` fleet ticks in transit —
-during which the stream's tokens are withheld, so the transfer lands in
-TTFT instead of vanishing between tiers — and the pages arrive via
-``PagedServeEngine.export_pages``/``import_pages`` (copy-free on the
-source, allocator-checked on both ends).  Both stage decisions AND the
-handoff transfer event ride the same fleet-global sequence, so the
-two-stage log still replays bit-for-bit, and ``margin_violations()``
+(:func:`repro.serve.tiers.handoff_seconds`).  The pages move in the
+same fleet tick through ``PagedServeEngine.export_pages``/
+``import_pages`` (copy-free on the source, allocator-checked on both
+ends), so a request is always resident on exactly one replica and its
+first token streams the tick its prefill completes.  Both stage
+decisions AND the handoff event ride the same fleet-global sequence, so
+the two-stage log still replays bit-for-bit, and ``margin_violations()``
 audits both stages with one rule.  A symmetric plan (or ``tiers=None``)
 keeps every stage a no-op: the fleet reproduces the single-stage router
 token-for-token on the same tick schedule — the tiered link of the
@@ -184,9 +183,8 @@ class FaultEvent:
     stream separately.  ``kind`` is one of ``kill``, ``corrupt``,
     ``degrade``, ``recover``, ``quarantine``, ``readmit``, ``lost`` or
     ``skip`` (an injector fault that found no eligible target); the
-    tiered fleet adds ``handoff`` (a KV transfer left its source) and
-    ``handoff_abort`` (the destination was gone or full at arrival) —
-    not faults, but transfers belong in the same total order so the
+    tiered fleet adds ``handoff`` (a KV transfer between tiers) — not a
+    fault, but transfers belong in the same total order so the
     two-stage log replays as ONE interleaving.
     """
 
@@ -200,24 +198,6 @@ class FaultEvent:
         """Compact identity for bit-identical replay comparison."""
         return (self.seq, self.tick, f"fault:{self.kind}", self.replica,
                 self.detail)
-
-
-@dataclasses.dataclass
-class _Transit:
-    """One KV handoff in flight between tiers.
-
-    While in transit the request is resident NOWHERE — the source freed
-    its pages at export, the destination allocates at arrival — and its
-    token stream (``held``) is withheld from the frontend so the
-    transfer's ticks land in TTFT.
-    """
-
-    req: Request
-    payload: dict
-    src: int
-    dst: int
-    arrive_tick: int
-    held: list[int]                    # generated tokens withheld in flight
 
 
 class FleetReplica:
@@ -417,8 +397,6 @@ class FleetEngine:
         self.migrations = 0
         self.rejected = 0
         self.handoffs = 0
-        self.handoff_aborts = 0
-        self._transit: list[_Transit] = []     # KV handoffs in flight
         self.deaths = 0
         self.quarantines = 0
         self.readmits = 0
@@ -549,11 +527,13 @@ class FleetEngine:
 
     def _collect_handoffs(self) -> None:
         """Stage 2: route every request whose prefill just completed on a
-        prefill-specialist replica to a decode-tier replica, export its
-        pages (copy-free on the source) and put the transfer in flight.
-        An unroutable request (decode tier saturated or down) simply
-        stays ``ready`` — it holds its pages and retries next tick, so
-        nothing is dropped and nothing decodes out of tier."""
+        prefill-specialist replica to a decode-tier replica and move its
+        pages there at once (copy-free on the source).  Routing checks
+        ``can_import`` at the destination and the import lands before
+        the next request is routed, so a routed handoff always fits.  An
+        unroutable request (decode tier saturated or down) simply stays
+        ``ready`` — it holds its pages and retries next tick, so nothing
+        is dropped and nothing decodes out of tier."""
         for r in self.replicas:
             if not (self.tiered and r.engine.hold_after_prefill
                     and r.dispatchable):
@@ -562,53 +542,12 @@ class FleetEngine:
                 target = self._route(req, "handoff", src=r)
                 if target is None:
                     continue
-                chosen = next(s for s in self.decisions[-1].scores
-                              if s.replica == target.index)
-                ticks = tiering.handoff_ticks(
-                    chosen.handoff_s, chosen.step_cost_s - chosen.handoff_s)
                 req, payload = r.engine.export_pages(req.uid)
-                # withhold the stream while the pages are in flight: the
-                # first token only reaches the frontend after arrival,
-                # so the transfer's ticks show up in TTFT
-                held, req.generated = req.generated, []
-                self._transit.append(_Transit(
-                    req=req, payload=payload, src=r.index,
-                    dst=target.index, arrive_tick=self.ticks + ticks,
-                    held=held))
+                target.engine.import_pages(req, payload)
                 self.handoffs += 1
                 self.record_event(
                     "handoff", r.index,
-                    (req.uid, target.index, payload["pages"], ticks))
-
-    def _abort_handoff(self, t: _Transit, why: str) -> None:
-        """Arrival failed (destination died/quarantined or its capacity
-        evaporated): roll the request back to the fleet queue for a full
-        re-prefill, exactly like a preemption rollback — greedy re-runs
-        regenerate the withheld prefix, so the stream stays byte-stable."""
-        t.req.generated = []
-        t.req.prefill_pos = 0
-        t.req.admit_seq = -1           # seniority is engine-local: reset
-        self.pending.appendleft(t.req)
-        self.handoff_aborts += 1
-        self.record_event("handoff_abort", t.dst, (t.req.uid, why))
-
-    def _arrive_handoffs(self) -> None:
-        """Land every transfer whose transit time has elapsed: allocate
-        on the destination, scatter the pages, release the withheld
-        tokens.  A destination that was killed/quarantined mid-flight
-        counts as a fault hit (the request classifies requeued/migrated,
-        never silently completed)."""
-        due = [t for t in self._transit if t.arrive_tick <= self.ticks]
-        for t in due:
-            self._transit.remove(t)
-            dst = self.replicas[t.dst]
-            if not dst.dispatchable:
-                self._fault_hit.add(t.req.uid)
-                self._abort_handoff(t, f"destination {dst.state}")
-                continue
-            t.req.generated = t.held
-            if not dst.engine.import_pages(t.req, t.payload):
-                self._abort_handoff(t, "destination out of capacity")
+                    (req.uid, target.index, payload["pages"]))
 
     # -- fault lifecycle (driven by repro.serve.faults, or directly) --------
 
@@ -723,9 +662,8 @@ class FleetEngine:
         serve (capacity died with its replicas).  Quarantined capacity
         counts as coming back, so its work waits instead of dying.  In
         a tiered fleet a queued request needs a PREFILL-tier home, and a
-        post-prefill request (ready or in transit) needs a decode-tier
-        home — if that whole tier died, its work is reaped, pages
-        released, nothing leaks."""
+        ready request needs a decode-tier home — if that whole tier
+        died, its work is reaped, pages released, nothing leaks."""
         alive = [r for r in self.replicas if r.state != DEAD]
         prefill_alive = [r for r in alive if r.prefill_tier]
         decode_alive = [r for r in alive if r.decode_tier]
@@ -743,19 +681,11 @@ class FleetEngine:
             self.pending.remove(req)
             self._lose(req, "no capable replica left")
         if self.tiered and not decode_alive:
-            for t in list(self._transit):
-                self._transit.remove(t)
-                self._lose(t.req, "decode tier died in flight")
             for r in self.replicas:
                 if not r.dispatchable:
                     continue
-                eng = r.engine
-                for req in list(eng.ready):
-                    eng.alloc.release(req.uid)
-                    eng.page_tables[req.slot][:] = 0
-                    eng.free_slots.append(req.slot)
-                    eng.ready.remove(req)
-                    req.slot = None
+                for req in list(r.engine.ready):
+                    r.engine.release(req)
                     self._lose(req, "decode tier died")
 
     def _lose(self, req: Request, why: str) -> None:
@@ -784,11 +714,6 @@ class FleetEngine:
                 self.pending.remove(req)
                 self._cancelled.add(uid)
                 return True
-        for t in self._transit:        # cancelled mid-handoff: the pages
-            if t.req.uid == uid:       # are in flight, resident nowhere
-                self._transit.remove(t)
-                self._cancelled.add(uid)
-                return True
         if any(r.engine.cancel(uid) for r in self.replicas):
             self._cancelled.add(uid)
             return True
@@ -803,25 +728,22 @@ class FleetEngine:
                    for r in self.replicas)
 
     def live(self) -> int:
-        return (len(self.pending) + len(self._transit)
+        return (len(self.pending)
                 + sum(r.engine.live_count() + len(r.engine.waiting)
                       for r in self.replicas))
 
     def step(self) -> int:
         """One fleet tick: inject due faults + detect corruption, lift
-        due quarantines, land due KV handoffs, dispatch, tick every
-        SERVING replica (index order), export newly-ready prefills to
-        the decode tier, migrate stranded rollbacks, reap doomed
-        requests.  Returns live requests.  With no injector, no faults
-        and a symmetric tier plan every added stage is a no-op, so an
-        N=1 or single-tier fleet still reproduces the single paged
-        engine tick-for-tick."""
+        due quarantines, dispatch, tick every SERVING replica (index
+        order), hand newly-ready prefills to the decode tier, migrate
+        stranded rollbacks, reap doomed requests.  Returns live
+        requests.  With no injector, no faults and a symmetric tier plan
+        every added stage is a no-op, so an N=1 or single-tier fleet
+        still reproduces the single paged engine tick-for-tick."""
         if self.injector is not None:
             self.injector.on_tick(self)
             self._detect()
         self._readmit_due()
-        if self._transit:
-            self._arrive_handoffs()
         with TraceAnnotation(spans.ROUTE):
             self._dispatch()
         for r in self.replicas:
@@ -866,17 +788,7 @@ class FleetEngine:
             assert req.uid not in owner, \
                 f"uid {req.uid} both pending and placed on r{owner[req.uid]}"
         assert not set(self.lost) & set(owner), "lost uid still owned"
-        # tiered invariants: an in-flight handoff is resident NOWHERE (its
-        # source freed the pages at export, the destination has not yet
-        # allocated — a stream can never sit in two tiers' page tables),
-        # and a prefill specialist never decodes
-        for t in self._transit:
-            assert t.req.uid not in owner, \
-                f"in-transit uid {t.req.uid} still owned by a replica"
-            holders = [r.index for r in self.replicas
-                       if t.req.uid in r.engine.alloc.pages]
-            assert not holders, \
-                f"in-transit uid {t.req.uid} holds pages on {holders}"
+        # tiered invariant: a prefill specialist never decodes
         for r in self.replicas:
             if r.engine.hold_after_prefill:
                 assert not r.engine.active, \
@@ -930,8 +842,6 @@ class FleetEngine:
             "decisions": len(self.decisions),
             "migrations": self.migrations,
             "handoffs": self.handoffs,
-            "handoff_aborts": self.handoff_aborts,
-            "in_transit": len(self._transit),
             "rejected": self.rejected,
             "deaths": self.deaths,
             "quarantines": self.quarantines,

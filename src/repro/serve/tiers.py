@@ -24,14 +24,14 @@ consumes it:
   carried as ``serving_spec().hbm_bytes_per_s``) pulls a replica toward
   the prefill tier, low P4 DRAM latency (``hbm_latency_s``) toward the
   decode tier.
-* :func:`handoff_bytes` / :func:`handoff_seconds` /
-  :func:`handoff_ticks` — the KV handoff between tiers modeled as a
-  paged-page transfer: whole source pages move at ``min(src, dst)``
-  measured global-memory bandwidth (the slower endpoint gates the
-  wire), plus one worst-endpoint DRAM round trip to start the burst.
-  The tick cost quantizes that against the destination's own decode
-  step so handoff latency lands in the fleet's tick clock — and
-  therefore in TTFT — instead of vanishing between tiers.
+* :func:`handoff_bytes` / :func:`handoff_seconds` — the price of the
+  KV handoff between tiers, modeled as a paged-page transfer: whole
+  source pages move at ``min(src, dst)`` measured global-memory
+  bandwidth (the slower endpoint gates the wire), plus one
+  worst-endpoint DRAM round trip to start the burst.  The router adds
+  it to each decode candidate's score.
+* :func:`handoff_ticks` — that price quantized against the
+  destination's decode step, for the planner's TTFT prediction.
 """
 
 from __future__ import annotations
@@ -184,7 +184,6 @@ def handoff_seconds(n_bytes: int, src_spec, dst_spec) -> float:
 
 def handoff_ticks(handoff_s: float, dst_step_s: float) -> int:
     """Quantize a handoff against the DESTINATION's decode step: the
-    ticks its batch turns over while the pages are in flight.  Never
-    zero — a handoff that cost nothing would vanish from TTFT, and the
-    whole point of pricing it is that it cannot."""
+    ticks its batch turns over while the pages move (the planner's TTFT
+    prediction).  Never zero."""
     return max(1, math.ceil(handoff_s / max(dst_step_s, 1e-12)))
